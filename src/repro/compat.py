@@ -1,10 +1,7 @@
-"""Version shims for the narrow set of JAX APIs whose home has moved.
+"""Small helpers shared by the model and the multi-device programs.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` (keyword
-``check_rep``) to ``jax.shard_map`` (keyword ``check_vma``).  Every
-shard_map island in this repo goes through this wrapper so both API
-generations run the multi-device tests (tests/dist_progs, the CI
-multi-device CPU job) unchanged.
+The repo runs on jax 0.9.0: ``jax.shard_map`` and ``jax.set_mesh`` are
+called directly, with no shims for older jax generations.
 """
 
 from __future__ import annotations
@@ -15,22 +12,14 @@ import jax
 
 
 def make_mesh(shape, axis_names):
-    """Build a Mesh over the first prod(shape) devices — the portable
-    spelling of ``jax.make_mesh(shape, names, axis_types=Auto)`` (the
-    ``axis_types`` keyword does not exist on older jax; Auto is the
-    default either way)."""
+    """Build a Mesh with Auto axes over the first prod(shape) devices, in
+    ``jax.devices()`` order (``jax.make_mesh`` would default to Explicit
+    axes and may reorder devices for the physical topology)."""
     import numpy as np
     n = math.prod(shape)
     return jax.sharding.Mesh(np.asarray(jax.devices()[:n]).reshape(shape),
                              axis_names)
 
-
-def use_mesh(mesh):
-    """Context manager entering ``mesh``: ``jax.set_mesh`` where it
-    exists, the legacy ``with mesh:`` context otherwise."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
 
 def causal_depthwise_conv(x, w, init=None):
     """Depthwise causal conv (VALID over [carry, x]) as K shifted
@@ -67,15 +56,3 @@ def causal_depthwise_conv(x, w, init=None):
             rows.append(r)
         out = out.at[:, :t_max].add(jnp.stack(rows, axis=1))
     return out
-
-
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:                                     # jax < 0.6: experimental home
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)

@@ -28,11 +28,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
-from repro.compat import shard_map
 
 NEG_INF = -1e30
 

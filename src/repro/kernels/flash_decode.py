@@ -47,8 +47,9 @@ the caller rebinds the result over the input, so XLA updates the pool
 buffers in place instead of functionally rebuilding the (large) arrays on
 every write — do NOT keep references to a pool you pass in.
 
-Validated against kernels/ref.decode_attention_ref in interpret mode
-(tests/test_kernels.py, tests/test_paged_engine.py).
+Checked against kernels/ref.decode_attention_ref in interpret mode
+(tests/test_kernels.py, tests/test_paged_engine.py) and compiled for a TPU
+v5e at yi-9b widths (tests/test_tpu_compile.py); on TPU they run natively.
 """
 
 from __future__ import annotations
@@ -61,60 +62,91 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.flash_attention import (NEG_INF, check_head_dim,
+                                           init_scratch, pad_seq, seq_block)
+
+
+def _decode_step(q_ref, k_ref, v_ref, valid, acc_scr, m_scr, l_scr, *,
+                scale: float, group: int, D: int):
+    """One online-softmax step of single-token attention for all heads.
+
+    q_ref: (1, H, D); k_ref/v_ref: (1, bk, KVH * D) — one KV block with its
+    heads on the lane axis; valid: (1, bk).  Each KV head's scores are
+    computed for every query row and kept for the rows of its group, so
+    the body needs no sublane slicing whatever the group size; decode is
+    bandwidth bound, so the redundant MXU work is free.  Scores, softmax
+    weights and both matmuls are f32, as in kernels/ref.py."""
+    q = q_ref[0].astype(jnp.float32) * scale                     # (H, D)
+    k = k_ref[0].astype(jnp.float32)                             # (bk, KVH*D)
+    v = v_ref[0].astype(jnp.float32)
+    KVH = k.shape[1] // D
+    head_kv = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0) // group
+    s = None
+    for h in range(KVH):
+        s_h = jax.lax.dot_general(q, k[:, h * D:(h + 1) * D],
+                                  (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        s = s_h if s is None else jnp.where(head_kv == h, s_h, s)
+    s = jnp.where(valid, s, NEG_INF)                             # (H, bk)
+    m_prev = m_scr[...]                                          # (H, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = None
+    for h in range(KVH):
+        p_h = jnp.where(head_kv == h, p, 0.0)
+        o_h = jax.lax.dot_general(p_h, v[:, h * D:(h + 1) * D],
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        pv = o_h if pv is None else pv + o_h
+    acc_scr[...] = acc_scr[...] * alpha + pv
+    m_scr[...] = m_new
+
+
+def _decode_finish(o_ref, lse_ref, acc_scr, m_scr, l_scr):
+    l = l_scr[...]
+    safe_l = jnp.where(l > 0.0, l, 1.0)
+    o_ref[0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
+    lse_ref[0] = jnp.where(l > 0.0, m_scr[...] + jnp.log(safe_l),
+                           NEG_INF).astype(lse_ref.dtype)         # (H, 1)
+
+
+def _decode_scratch(H: int, D: int):
+    return [pltpu.VMEM((H, D), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32)]
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                    acc_scr, m_scr, l_scr,
-                   *, scale: float, nk: int, bk: int, group: int,
-                   window: Optional[int], kv_offset: int):
+                   *, scale: float, nk: int, bk: int, group: int, D: int,
+                   window: Optional[int], kv_offset: int,
+                   kv_len: Optional[int]):
+    b = pl.program_id(0)
     ik = pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        init_scratch(acc_scr, m_scr, l_scr)
 
-    length = len_ref[0]
-    kv_pos = kv_offset + ik * bk + jax.lax.broadcasted_iota(
-        jnp.int32, (1, bk), 1)[0]                                # (bk,)
+    length = len_ref[b]
+    idx = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    kv_pos = kv_offset + idx                                     # (1, bk)
     valid = kv_pos < length
     if window is not None:
         valid &= kv_pos >= (length - window)
+    if kv_len is not None:                # cache padded up to whole blocks
+        valid &= idx < kv_len
 
     @pl.when(jnp.any(valid))
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale                 # (H, D)
-        k = k_ref[0].astype(jnp.float32)                         # (bk, KVH, D)
-        v = v_ref[0].astype(jnp.float32)
-        KVH = k.shape[1]
-        H, D = q.shape
-        qg = q.reshape(KVH, group, D)
-        # batched over kv heads: (KVH, group, bk)
-        s = jax.lax.dot_general(
-            qg, k.transpose(1, 0, 2), (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        s = jnp.where(valid[None, None, :], s, NEG_INF)
-        m_prev = m_scr[...]                                      # (H,)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1).reshape(H))
-        p = jnp.exp(s - m_new.reshape(KVH, group)[:, :, None])
-        p = jnp.where(valid[None, None, :], p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)                          # (H,)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1).reshape(H)
-        pv = jax.lax.dot_general(
-            p, v.transpose(1, 0, 2), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)                  # (KVH, group, D)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv.reshape(H, D)
-        m_scr[...] = m_new
+        _decode_step(q_ref, k_ref, v_ref, valid, acc_scr, m_scr, l_scr,
+                    scale=scale, group=group, D=D)
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        l = l_scr[...]
-        safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0] = (acc_scr[...] / safe_l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(l > 0.0, m_scr[...] + jnp.log(safe_l), NEG_INF
-                               ).astype(lse_ref.dtype)
+        _decode_finish(o_ref, lse_ref, acc_scr, m_scr, l_scr)
 
 
 @functools.partial(
@@ -136,40 +168,43 @@ def flash_decode(
 ) -> jax.Array | Tuple[jax.Array, jax.Array]:
     B, H, D = q.shape
     _, S, KVH, _ = k_cache.shape
+    check_head_dim(D, interpret)
     group = H // KVH
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
-    bk = min(block_k, S)
-    assert S % bk == 0, (S, bk)
-    nk = S // bk
+    bk, S_p = seq_block(S, block_k)
+    nk = S_p // bk
+    k3 = pad_seq(k_cache, S_p).reshape(B, S_p, KVH * D)
+    v3 = pad_seq(v_cache, S_p).reshape(B, S_p, KVH * D)
 
     kernel = functools.partial(_decode_kernel, scale=scale, nk=nk, bk=bk,
-                               group=group, window=window, kv_offset=kv_offset)
-    out, lse = pl.pallas_call(
-        kernel,
+                               group=group, D=D, window=window,
+                               kv_offset=kv_offset,
+                               kv_len=S if S_p != S else None)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,         # lengths
         grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, ik: (b,)),
-            pl.BlockSpec((1, H, D), lambda b, ik: (b, 0, 0)),
-            pl.BlockSpec((1, bk, KVH, D), lambda b, ik: (b, ik, 0, 0)),
-            pl.BlockSpec((1, bk, KVH, D), lambda b, ik: (b, ik, 0, 0)),
+            pl.BlockSpec((1, H, D), lambda b, ik, ln: (b, 0, 0)),
+            pl.BlockSpec((1, bk, KVH * D), lambda b, ik, ln: (b, ik, 0)),
+            pl.BlockSpec((1, bk, KVH * D), lambda b, ik, ln: (b, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, H, D), lambda b, ik: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, ik: (b, 0)),
+            pl.BlockSpec((1, H, D), lambda b, ik, ln: (b, 0, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, ik, ln: (b, 0, 0)),
         ],
+        scratch_shapes=_decode_scratch(H, D),
+    )
+    out, lse = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(lengths, q, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), q, k3, v3)
     if with_lse:
-        return out, lse
+        return out, lse[..., 0]
     return out
 
 
@@ -305,9 +340,8 @@ def copy_kv_block_within(pool: jax.Array, src_block: jax.Array,
 # calls these every chunk/tick with the same mesh, so the shard_map closure
 # and its donation setup are built once.
 
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 
 
 @functools.lru_cache(maxsize=None)
@@ -482,15 +516,13 @@ POS_PAD = jnp.int32(2 ** 30)
 def _paged_decode_kernel(bt_ref, len_ref, pp_ref, q_ref, k_ref, v_ref,
                          o_ref, lse_ref, acc_scr, m_scr, l_scr,
                          *, scale: float, nk: int, bk: int, group: int,
-                         window: Optional[int]):
+                         D: int, window: Optional[int]):
     b = pl.program_id(0)
     ik = pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        init_scratch(acc_scr, m_scr, l_scr)
 
     length = len_ref[b]
     # logical position of each slot: the prefetched page_pos gives the
@@ -499,42 +531,19 @@ def _paged_decode_kernel(bt_ref, len_ref, pp_ref, q_ref, k_ref, v_ref,
     # happened in the index map, the *logical* one happens here, so window
     # masks are native however the pages are striped
     kv_pos = pp_ref[b, ik] + jax.lax.broadcasted_iota(
-        jnp.int32, (1, bk), 1)[0]
+        jnp.int32, (1, bk), 1)
     valid = kv_pos < length
     if window is not None:
         valid &= kv_pos >= (length - window)
 
     @pl.when(jnp.any(valid))
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale                 # (H, D)
-        k = k_ref[0].astype(jnp.float32)                         # (bk, KVH, D)
-        v = v_ref[0].astype(jnp.float32)
-        KVH = k.shape[1]
-        H, D = q.shape
-        qg = q.reshape(KVH, group, D)
-        s = jax.lax.dot_general(
-            qg, k.transpose(1, 0, 2), (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        s = jnp.where(valid[None, None, :], s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1).reshape(H))
-        p = jnp.exp(s - m_new.reshape(KVH, group)[:, :, None])
-        p = jnp.where(valid[None, None, :], p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1).reshape(H)
-        pv = jax.lax.dot_general(
-            p, v.transpose(1, 0, 2), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv.reshape(H, D)
-        m_scr[...] = m_new
+        _decode_step(q_ref, k_ref, v_ref, valid, acc_scr, m_scr, l_scr,
+                    scale=scale, group=group, D=D)
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        l = l_scr[...]
-        safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0] = (acc_scr[...] / safe_l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(l > 0.0, m_scr[...] + jnp.log(safe_l),
-                               NEG_INF).astype(lse_ref.dtype)
+        _decode_finish(o_ref, lse_ref, acc_scr, m_scr, l_scr)
 
 
 @functools.partial(
@@ -555,7 +564,8 @@ def paged_flash_decode(
 ) -> jax.Array | Tuple[jax.Array, jax.Array]:
     """Flash decode straight off the paged pool: the block table is a
     scalar-prefetch argument and the KV BlockSpec index map dereferences it,
-    so each (b, ik) grid step DMAs physical page ``block_tables[b, ik]``.
+    so each (b, ik) grid step DMAs physical page ``block_tables[b, ik]``
+    (all KV heads of it, viewed as ``(page, KVH * D)``).
 
     ``page_pos[b, j]`` is the logical position of page j's first token
     (default: flat table order, ``j * page``).  A shard of a striped pool
@@ -565,47 +575,47 @@ def paged_flash_decode(
     Columns past the allocation should carry ``POS_PAD`` so they mask out.
     """
     B, H, D = q.shape
-    _, bk, KVH, _ = k_pool.shape
+    n_pages, bk, KVH, _ = k_pool.shape
     nk = block_tables.shape[1]
+    check_head_dim(D, interpret)
     group = H // KVH
     scale = softmax_scale if softmax_scale is not None else D ** -0.5
     if page_pos is None:
         page_pos = jnp.broadcast_to(
             jnp.arange(nk, dtype=jnp.int32)[None] * bk, (B, nk))
+    kp = k_pool.reshape(n_pages, bk, KVH * D)
+    vp = v_pool.reshape(n_pages, bk, KVH * D)
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale, nk=nk,
-                               bk=bk, group=group, window=window)
+                               bk=bk, group=group, D=D, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,         # block_tables, lengths, page_pos
         grid=(B, nk),
         in_specs=[
             pl.BlockSpec((1, H, D), lambda b, ik, bt, ln, pp: (b, 0, 0)),
-            pl.BlockSpec((1, bk, KVH, D),
-                         lambda b, ik, bt, ln, pp: (bt[b, ik], 0, 0, 0)),
-            pl.BlockSpec((1, bk, KVH, D),
-                         lambda b, ik, bt, ln, pp: (bt[b, ik], 0, 0, 0)),
+            pl.BlockSpec((1, bk, KVH * D),
+                         lambda b, ik, bt, ln, pp: (bt[b, ik], 0, 0)),
+            pl.BlockSpec((1, bk, KVH * D),
+                         lambda b, ik, bt, ln, pp: (bt[b, ik], 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, H, D), lambda b, ik, bt, ln, pp: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, ik, bt, ln, pp: (b, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, ik, bt, ln, pp: (b, 0, 0)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((H, D), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-        ],
+        scratch_shapes=_decode_scratch(H, D),
     )
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(block_tables, lengths, page_pos, q, k_pool, v_pool)
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      page_pos.astype(jnp.int32), q, kp, vp)
     if with_lse:
-        return out, lse
+        return out, lse[..., 0]
     return out
 
 

@@ -1,10 +1,19 @@
 """Backend-dispatching wrappers around the Pallas kernels.
 
-On TPU the Pallas kernels run natively; on CPU (this container, and the unit
-tests) the pure-jnp oracles in ref.py are the execution path — identical
-math, identical shapes, so sharding/collective structure of the surrounding
-program is unchanged.  ``impl="interpret"`` forces the Pallas kernel bodies
-through the interpreter for kernel validation.
+On TPU the Pallas kernels run natively (``impl="pallas"``, the default
+there); tests/test_tpu_compile.py compiles each main-path kernel for a TPU
+v5e at yi-9b widths, so a kernel the chip's compiler would refuse fails the
+tests on any machine.  On CPU (the unit tests) the pure-jnp oracles in
+ref.py are the execution path — identical math, identical shapes, so the
+sharding/collective structure of the surrounding program is unchanged.
+``impl="interpret"`` forces the Pallas kernel bodies through the
+interpreter, which checks their numerics against the oracles on CPU.
+
+A Pallas kernel that cannot take a shape raises; it never hands the call
+to an oracle.  The sharded (3-dim table) pool layout has only gather
+oracles here, so under ``impl="pallas"``/``"interpret"`` it raises too: on
+the engine's mesh path decode runs the split-KV island and causal prefill
+chunks of any length ride the ring (models/attention.py).
 """
 
 from __future__ import annotations
@@ -30,6 +39,15 @@ def default_impl() -> str:
     if _FORCED:
         return _FORCED
     return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+def _no_sharded_kernel(impl: str, op: str, path: str) -> None:
+    """Refuse the sharded pool layout under a Pallas impl: only the gather
+    oracle reads it, and it must not stand in for a kernel unseen."""
+    if impl not in ("ref", "ref_blocked"):
+        raise NotImplementedError(
+            f"{op} under impl={impl!r}: no Pallas kernel reads the sharded "
+            f"(3-dim table) pool layout directly; run it through {path}")
 
 
 def attention(q, k, v, q_pos, kv_pos, *, causal: bool = True,
@@ -98,10 +116,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
 
     A *sequence-parallel sharded* pool (3-dim block_tables (n_shards, B,
     npg_local), 5-dim pools — serving/cache_manager with kv_shards > 1)
-    is served by the logical-order gather oracle regardless of ``impl``:
-    the distributed execution path for that layout is the shard_map
-    split-KV island (core/ring_attention.sharded_paged_decode), whose
-    per-shard partials dispatch back here with the shard-local 2-dim
+    has only the logical-order gather oracle (``impl="ref"``); the Pallas
+    impls raise on it.  The distributed execution path for that layout is
+    the shard_map split-KV island (core/ring_attention.sharded_paged_decode),
+    whose per-shard partials dispatch back here with the shard-local 2-dim
     layout + ``page_pos``.
     """
     impl = impl or default_impl()
@@ -113,6 +131,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
             softmax_scale=softmax_scale, with_lse=with_lse,
             impl=("ref" if impl in ("ref", "ref_blocked") else impl))
     if block_tables.ndim == 3:
+        _no_sharded_kernel(impl, "paged_decode_attention",
+                           "core/ring_attention.sharded_paged_decode")
         return _ref.paged_decode_attention_ref(
             q, k_pool, v_pool, block_tables, lengths, window=window,
             softmax_scale=softmax_scale, with_lse=with_lse)
@@ -149,13 +169,16 @@ def paged_prefill_attention(q, k_new, v_new, q_pos, kv_pos_new,
     interpreter for validation.
 
     The sequence-parallel sharded pool layout (3-dim block_tables, 5-dim
-    pools) always takes the gather oracle: distributed execution of that
-    layout is ``core/ring_attention.ring_paged_prefill`` (history pages
-    rotate through the ring), and this fallback only serves chunks whose
-    length does not divide over the ring axis.
+    pools) has only the gather oracle (``impl="ref"``); the Pallas impls
+    raise on it.  Distributed execution of that layout is
+    ``core/ring_attention.ring_paged_prefill`` (history pages rotate
+    through the ring; models/attention.py pads causal chunks of any length
+    onto it).
     """
     impl = impl or default_impl()
     if block_tables.ndim == 3:
+        _no_sharded_kernel(impl, "paged_prefill_attention",
+                           "core/ring_attention.ring_paged_prefill")
         return _ref.paged_prefill_attention_ref(
             q, k_new, v_new, q_pos, kv_pos_new, k_pool, v_pool,
             block_tables, hist_len, causal=causal, window=window,

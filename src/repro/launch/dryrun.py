@@ -25,10 +25,13 @@ import jax
 from repro.configs.registry import (ASSIGNED, get_config, input_specs,
                                     supports_shape)
 from repro.models.config import INPUT_SHAPES
-from repro.compat import use_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import analyse, collective_bytes
 from repro.launch.steps import build_step, scanned_param_bytes_per_dev
+
+# the production mesh is a TPU v5e pod slice (launch/mesh.py); the compile
+# runs on placeholder host devices, so the roofline peaks are named here
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
@@ -58,7 +61,7 @@ def _cost_terms(cfg, shape, mesh, n_blocks: int,
         n_encoder_layers=(n_blocks if cfg.encoder_decoder else 0))
     fn, in_sh, args = build_step(small, shape, mesh, unroll_scan=True,
                                  ctx_overrides=ctx_overrides)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_sh).lower(*args).compile()
         cost = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
@@ -106,7 +109,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool = False,
     #    bounds attention temp memory the way the TPU flash kernel does.
     fn, in_sh, args = build_step(cfg, shape, mesh, impl="ref_blocked",
                                  ctx_overrides=overrides)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -118,7 +121,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool = False,
     peak = getattr(mem, "temp_size_in_bytes", 0) + \
         getattr(mem, "argument_size_in_bytes", 0) + \
         getattr(mem, "output_size_in_bytes", 0)
-    roof = analyse(arch, shape, mesh_name, chips, cfg, cost, hlo_text="",
+    roof = analyse(arch, shape, mesh_name, chips, cfg, cost,
+                   device_kind=TARGET_DEVICE_KIND, hlo_text="",
                    peak_mem=peak, coll=cost)
     dtype_bytes = 4 if shape.kind == "train" else 2
     scan_params = scanned_param_bytes_per_dev(cfg, mesh,

@@ -30,9 +30,23 @@ from typing import Dict, Optional
 
 from repro.models.config import InputShape, ModelConfig
 
-PEAK_FLOPS = 197e12          # TPU v5e bf16 / chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (~)
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of
+# inter-chip interconnect over 4 links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Published peaks of one chip of ``device_kind``; an unknown kind is
+    an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            "to launch/roofline.PEAKS with their source") from None
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8": 1,
                 "s64": 8, "s32": 4, "u64": 8, "u32": 4, "s16": 2, "u16": 2,
@@ -161,8 +175,9 @@ class Roofline:
 
 
 def analyse(arch: str, shape: InputShape, mesh_name: str, chips: int,
-            cfg: ModelConfig, cost: dict, hlo_text: str = "",
-            peak_mem: float = 0.0, coll: Optional[dict] = None) -> Roofline:
+            cfg: ModelConfig, cost: dict, *, device_kind: str,
+            hlo_text: str = "", peak_mem: float = 0.0,
+            coll: Optional[dict] = None) -> Roofline:
     flops = float(cost.get("flops", 0.0))
     mem_bytes = float(cost.get("bytes accessed", 0.0))
     if coll is not None:
@@ -170,10 +185,11 @@ def analyse(arch: str, shape: InputShape, mesh_name: str, chips: int,
                 **coll.get("coll_detail", {})}
     else:
         coll = collective_bytes(hlo_text)
-    compute_s = flops / PEAK_FLOPS
-    memory_s = mem_bytes / HBM_BW
-    memory_adj_s = peak_mem / HBM_BW
-    collective_s = coll["total"] / ICI_BW
+    peak = peaks(device_kind)
+    compute_s = flops / peak["flops"]
+    memory_s = mem_bytes / peak["hbm_bw"]
+    memory_adj_s = peak_mem / peak["hbm_bw"]
+    collective_s = coll["total"] / peak["ici_bw"]
     terms_adj = {"compute": compute_s, "memory": memory_adj_s,
                  "collective": collective_s}
     terms_hlo = {"compute": compute_s, "memory": memory_s,

@@ -20,6 +20,7 @@ from repro.core.ring_attention import (ring_attention, ring_paged_prefill,
                                        sharded_cache_update,
                                        sharded_paged_decode, split_kv_decode)
 from repro.kernels import ops
+from repro.kernels.flash_decode import POS_PAD
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_rope, rms_norm
 from repro.models.sharding import ExecContext
@@ -53,6 +54,22 @@ def _qkv_specs(cfg: ModelConfig, ctx: ExecContext, seq_axis):
     h_ax = ctx.shardable(cfg.padded_heads, ctx.tp_axis)
     kv_ax = ctx.shardable(cfg.n_kv_heads, ctx.tp_axis)
     return h_ax, kv_ax, seq_axis
+
+
+def _ring_pad(x: jax.Array, pos: jax.Array, n: int):
+    """Pad a chunk operand (axis 1) and its (B, S) positions up to a
+    multiple of the ring size ``n``, which the shard_map ring islands need.
+    Padded slots sit at ``POS_PAD``, past every real position: as causal
+    keys they are masked for every real query, and their own query rows
+    are sliced off by the caller.  A Pallas kernel cannot be partitioned
+    by GSPMD, so on TPU a sequence-sharded chunk must ride the ring."""
+    pad = (-x.shape[1]) % n
+    if not pad:
+        return x, pos
+    x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+    pos = jnp.pad(jnp.broadcast_to(pos, (x.shape[0], pos.shape[-1])),
+                  [(0, 0), (0, pad)], constant_values=POS_PAD)
+    return x, pos
 
 
 def attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
@@ -269,24 +286,28 @@ def attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
                 "history pool on every device.  Either hand over the "
                 "sharded layout or run with ctx.with_(sp_axis=None).")
         if (history["block_table"].ndim == 3 and sp_n > 1
-                and S % sp_n == 0):
+                and (causal or S % sp_n == 0)):
             # sharded pool + ring attention: the chunk's queries/KV ride
             # the ring as usual and each shard's history pages rotate
             # along with them — no dense history view, no page migration
+            qr, qpos = _ring_pad(q, pos2d, sp_n)
+            kr, _ = _ring_pad(k, pos2d, sp_n)
+            vr, _ = _ring_pad(v, pos2d, sp_n)
             o = ring_paged_prefill(
-                q, k, v, pos2d, pos2d, history["k_pool"],
+                qr, kr, vr, qpos, qpos, history["k_pool"],
                 history["v_pool"], history["block_table"], history["len"],
                 mesh=ctx.mesh, sp_axis=ctx.sp_axis, head_axis=h_ax,
                 kv_head_axis=kv_ax if h_ax is not None else None,
                 batch_axis=ctx.pod_axis, causal=causal,
                 window=window, impl=ctx.impl,
-                active_shards=ctx.active_pool_shards)
+                active_shards=ctx.active_pool_shards)[:, :S]
         else:
-            # single-group chunk, or a chunk length that does not divide
-            # over the ring: the gather fallback handles both pool
-            # layouts (sharded reads go through the logical-order view —
-            # which stripes over exactly the table's leading rows, so an
-            # elastically narrowed pool hands over only its active rows)
+            # single-group chunk, or a non-causal chunk that does not
+            # divide over the ring: the paged prefill op.  A sharded pool
+            # here has only the gather oracle (impl "ref"; the Pallas
+            # impls raise), whose logical-order view stripes over exactly
+            # the table's leading rows, so an elastically narrowed pool
+            # hands over only its active rows
             bt = history["block_table"]
             if bt.ndim == 3 and ctx.active_pool_shards:
                 bt = bt[:min(ctx.active_pool_shards, bt.shape[0])]
@@ -306,16 +327,20 @@ def attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
             hpos = jnp.broadcast_to(hpos[None], (B, hpos.shape[0]))
         kv_pos = jnp.concatenate([hpos, pos2d], axis=1)
 
-    sp_ok = (ctx.sp_axis is not None and ctx.mesh is not None
-             and S % ctx.axis_size(ctx.sp_axis) == 0
-             and k.shape[1] % ctx.axis_size(ctx.sp_axis) == 0)
-    if sp_ok:
-        o = ring_attention(q, k, v, pos2d, kv_pos, mesh=ctx.mesh,
+    sp_n = (ctx.axis_size(ctx.sp_axis)
+            if ctx.sp_axis is not None and ctx.mesh is not None else 1)
+    even = S % sp_n == 0 and k.shape[1] % sp_n == 0
+    if sp_n > 1 and (causal or even):
+        qr, qpos = _ring_pad(q, pos2d, sp_n)
+        kr, kpos = _ring_pad(k, kv_pos, sp_n)
+        vr, _ = _ring_pad(v, kv_pos, sp_n)
+        o = ring_attention(qr, kr, vr, qpos, kpos, mesh=ctx.mesh,
                            sp_axis=ctx.sp_axis, head_axis=h_ax,
                            kv_head_axis=kv_ax, batch_axis=ctx.pod_axis,
                            causal=causal, window=window,
                            impl=ctx.impl,
-                           zigzag_skip=(ctx.zigzag_skip and history is None))
+                           zigzag_skip=(ctx.zigzag_skip and history is None
+                                        and even))[:, :S]
     else:
         o = ops.attention(q, k, v, pos2d, kv_pos, causal=causal,
                           window=window, impl=ctx.impl)

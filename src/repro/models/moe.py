@@ -25,13 +25,12 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
 from repro.models.layers import mlp
 from repro.models.sharding import ExecContext
-from repro.compat import shard_map
 
 GROUP_SIZE = 512
 

@@ -8,6 +8,7 @@ ShapeDtypeStructs for dry-run lowering without allocation.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -185,6 +186,13 @@ def param_specs(cfg: ModelConfig, ctx: ExecContext) -> dict:
 
 
 # -------------------------------------------------------------------- init
+@functools.partial(jax.jit, static_argnames=("shape", "std", "dtype"))
+def _scaled_normal(key, shape, std, dtype):
+    # one fused program: a bf16 weight stack never materialises its float32
+    # draw, which at full width would not fit next to the weights on a chip
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
 def init_params(cfg: ModelConfig, key: jax.Array,
                 dtype: Optional[str] = None) -> dict:
     dtype = jnp.dtype(dtype or "float32")
@@ -214,7 +222,7 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         else:
             fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
             std = 1.0 / math.sqrt(max(fan_in, 1))
-            v = (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
+            v = _scaled_normal(k, shape, std, dtype)
         inits.append(v)
     params = jax.tree.unflatten(treedef, inits)
 

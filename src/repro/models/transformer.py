@@ -20,6 +20,7 @@ attention block consumes the table natively.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -97,11 +98,21 @@ def _residual_spec(ctx: ExecContext, mode: str):
     return (ctx.batch_axes, None, None)       # decode
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "ctx", "mode", "causal", "pattern"))
 def _stack_forward(x, blocks_p, cfg: ModelConfig, ctx: ExecContext, positions,
                    mode: str, caches, cache_len, encoder_out,
                    causal: bool, pattern, history=None):
-    """Scan over the stacked pattern blocks."""
+    """Scan over the stacked pattern blocks.
+
+    Jitted so that an eagerly called forward (the serving engine's chunks
+    and ticks) reuses the compiled layer stack for every call of the same
+    shapes: an eager ``lax.scan`` traces a fresh body closure per call and
+    so compiled the whole stack again on every decode tick."""
     res_spec = _residual_spec(ctx, mode)
+    # constrained inside the jit: a sequence that does not divide the SP
+    # axis shards unevenly here, where an eager constraint would refuse it
+    x = ctx.constrain(x, *res_spec)
 
     def body(carry, xs):
         x, aux_tot = carry
@@ -162,9 +173,6 @@ def forward(params: dict, cfg: ModelConfig, ctx: ExecContext,
     x = embed(tokens, params["embed"], dtype)
     if cfg.pos_embedding == "learned":
         x = x + learned_pos(positions, params["pos_emb"], dtype)
-    res_spec = _residual_spec(ctx, mode)
-    x = ctx.constrain(x, *res_spec)
-
     encoder_out = None
     if cfg.encoder_decoder:
         if mode == "decode":

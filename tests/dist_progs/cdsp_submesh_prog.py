@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import use_mesh
 from repro.configs.registry import get_config
 from repro.models.params import init_params
 from repro.models.sharding import CPU_CTX, ExecContext
@@ -50,7 +49,7 @@ ctx2 = ExecContext(mesh=mesh2, sp_axis="sp")
 p2 = put(params, mesh2, lambda x: P())
 t0 = jax.device_put(tokens[:, :L0], NamedSharding(mesh2, P(None, "sp")))
 pos0 = jax.device_put(pos[:, :L0], NamedSharding(mesh2, P(None, "sp")))
-with use_mesh(mesh2):
+with jax.set_mesh(mesh2):
     logits0, _, caches0 = jax.jit(
         lambda p, t, ps: forward(p, cfg, ctx2, t, ps, "prefill"))(p2, t0, pos0)
 
@@ -74,7 +73,7 @@ ctx4 = ExecContext(mesh=mesh4, sp_axis="sp")
 p4 = put(params, mesh4, lambda x: P())
 t1 = jax.device_put(tokens[:, L0:], NamedSharding(mesh4, P(None, "sp")))
 pos1 = jax.device_put(pos[:, L0:], NamedSharding(mesh4, P(None, "sp")))
-with use_mesh(mesh4):
+with jax.set_mesh(mesh4):
     logits1, _, _ = jax.jit(
         lambda p, t, ps, h: forward(p, cfg, ctx4, t, ps, "prefill",
                                     history=h))(p4, t1, pos1, history)

@@ -81,10 +81,11 @@ mesh = jax.sharding.Mesh(np.array(jax.devices()), ("x",))
 ctx = ExecContext(mesh=mesh, sp_axis="x", kv_split_axis="x")
 
 rng = np.random.default_rng(42)
-# 64 -> chunks of 32 (ring 4 | 32); 56 -> chunks of 28 (gather fallback);
-# both paths must agree with the oracle bit-for-bit at the token level
+# 64 -> chunks of 32 and 56 -> chunks of 28 divide the 4-way ring; 58 ->
+# chunks of 29 do not, and ride the ring padded to 32 (masked slots): every
+# path must agree with the oracle at the token level
 prompts = [rng.integers(0, cfg.vocab_size, L).astype(np.int32)
-           for L in (64, 56, 64)]
+           for L in (64, 56, 64, 58)]
 # twin prompt: request 2 repeats request 0 -> prefix sharing on the
 # striped decode pool (shared blocks + CoW splits cross the islands)
 prompts[2] = prompts[0].copy()

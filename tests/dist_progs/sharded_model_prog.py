@@ -18,7 +18,7 @@ from repro.models.sharding import CPU_CTX, ExecContext
 from repro.models.transformer import forward
 
 assert jax.device_count() == 8
-from repro.compat import make_mesh, use_mesh
+from repro.compat import make_mesh
 mesh = make_mesh((4, 2), ("data", "model"))
 
 for arch in ("yi-9b", "mamba2-1.3b", "jamba-1.5-large-398b"):

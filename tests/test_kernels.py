@@ -22,6 +22,7 @@ def _rand(key, shape, dtype):
     (2, 256, 256, 4, 2, 64),
     (2, 128, 384, 8, 8, 64),     # MHA, Sq != Sk (CDSP chunk w/ history)
     (1, 512, 512, 4, 1, 128),    # MQA, head_dim 128
+    (1, 200, 333, 4, 2, 64),     # lengths off the block grid: padded
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_sweep(B, Sq, Sk, H, KVH, D, dtype):
@@ -72,7 +73,8 @@ def test_flash_attention_zigzag_positions():
 
 
 @pytest.mark.parametrize("B,S,H,KVH,D", [
-    (2, 256, 4, 2, 64), (3, 512, 8, 8, 64), (1, 1024, 8, 1, 128)])
+    (2, 256, 4, 2, 64), (3, 512, 8, 8, 64), (1, 1024, 8, 1, 128),
+    (2, 300, 4, 2, 64)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_decode_sweep(B, S, H, KVH, D, dtype):
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
@@ -295,3 +297,28 @@ def test_page_helper_donation_no_copy():
     pool = fd.copy_kv_block_within(pool, jnp.asarray(6, jnp.int32),
                                    jnp.asarray(7, jnp.int32))
     assert pool.unsafe_buffer_pointer() == ptr
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas", "interpret"])
+def test_sharded_pool_layout_only_on_the_gather_oracle(impl):
+    """Only the gather oracle reads the sharded (3-dim table) pool layout:
+    under a Pallas impl the ops raise instead of handing it the call."""
+    from repro.kernels import ops
+    n, B, npg, page, KVH, H, D, S = 2, 1, 2, 8, 1, 2, 16, 4
+    pool = jnp.zeros((n, npg + 1, page, KVH, D), jnp.float32)
+    bt = jnp.zeros((n, B, npg), jnp.int32)
+    lens = jnp.ones((B,), jnp.int32)
+    q = jnp.zeros((B, S, H, D), jnp.float32)
+    kv = jnp.zeros((B, S, KVH, D), jnp.float32)
+    pos = jnp.arange(S, dtype=jnp.int32)[None] + 1
+    calls = (
+        lambda: ops.paged_decode_attention(q[:, 0], pool, pool, bt, lens,
+                                           impl=impl),
+        lambda: ops.paged_prefill_attention(q, kv, kv, pos, pos, pool, pool,
+                                            bt, lens, impl=impl))
+    for call in calls:
+        if impl == "ref":
+            assert bool(jnp.all(jnp.isfinite(call())))
+        else:
+            with pytest.raises(NotImplementedError, match="sharded"):
+                call()

@@ -1,10 +1,11 @@
 """Roofline extraction: HLO collective parsing + model-FLOPs accounting."""
 
 import jax.numpy as jnp
+import pytest
 
 from repro.configs.registry import get_config
 from repro.launch.roofline import (_shape_bytes, collective_bytes,
-                                   model_flops)
+                                   model_flops, peaks)
 from repro.models.config import INPUT_SHAPES
 
 HLO = """
@@ -57,3 +58,12 @@ def test_long500k_window_capping():
     # attention term must be capped at the window, not 524288
     cap = 2.0 * cfg.active_param_count() * 1 + 4.0 * d * attn_layers * 4096
     assert fl <= cap * 1.01
+
+
+def test_peaks_keyed_by_device_kind():
+    """Peaks come from a table keyed by jax's device_kind; a device that is
+    not in it is an error, never silently given v5e numbers."""
+    v5e = peaks("TPU v5 lite")
+    assert v5e["flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")
