@@ -94,7 +94,7 @@ from repro.serving.kv_offload import (HostKVPool, HostPrefixCache,
                                       choose_preempt_policy)
 from repro.serving.request import Phase, Request
 from repro.serving.simulator import ClusterSpec, Policy, Simulator
-from repro.serving.telemetry import OpProfiler
+from repro.serving.telemetry import span
 from repro.serving.transfer import TransferManager
 
 
@@ -411,8 +411,7 @@ class ServingEngine(Simulator):
                  interconnect: Optional[InterconnectModel] = None,
                  decode_hosts: Optional[Dict[int, tuple]] = None,
                  piggyback: bool = True,
-                 decode_budget: Optional[int] = None,
-                 profile_ops: bool = False):
+                 decode_budget: Optional[int] = None):
         # the tracer is always on in the real engine — the preempt/
         # restripe/mixed log views below are backed by it
         super().__init__(spec, policy, decode_model, trace=True)
@@ -434,10 +433,6 @@ class ServingEngine(Simulator):
         self.prompts: Dict[int, np.ndarray] = {}
         self.outputs: Dict[int, List[int]] = {}
         self.chunk_log: Dict[int, List[dict]] = {}
-        # optional wall-clock profiling around the jitted page ops
-        # (fused tick, chunk scatter, restripe all-to-all) -> named
-        # op_wall_us/* histograms in the metrics registry
-        self.profiler = OpProfiler(self.metrics, enabled=profile_ops)
         # sequence-parallel sharded pools: prefill stripes over sp_axis
         # (ring-paged history), decode over kv_split_axis (split-KV paged
         # decode).  Admission moves pages between the two pools with
@@ -687,37 +682,41 @@ class ServingEngine(Simulator):
         return self.fabric.peer_copy_cost(n_blocks) < rec_s
 
     def _on_arrive(self, now: float, rid: int) -> None:
-        self._price_piggyback(now)
-        # engine-level controller observes arrivals unless the policy owns
-        # the same controller (DynamicTetrisPolicy observes via on_arrival)
-        if (self.controller is not None
-                and getattr(self.policy, "controller", None)
-                is not self.controller):
-            self.controller.observe(now)
-        skip = self._host_prefix_skip(rid)
-        if skip:
-            # host-cache-aware plan: only the uncached remainder is
-            # chunked; the cached prefix rides in as promoted pages
-            req = self.reqs[rid]
-            self.tracer.record(now, "arrive", rid=rid,
-                               track=("request", rid), host_skip=skip)
-            self.policy.on_arrival(now)
-            shadow = Request(rid=rid, arrival=now,
-                             prompt_len=req.prompt_len - skip,
-                             output_len=req.output_len, cached_tokens=skip)
-            alloc = self.policy.plan(shadow, self._pool_view(now), now)
-            if alloc is None:
-                self.rejected.append(rid)
-                self.tracer.record(now, "reject", rid=rid,
-                                   track=("request", rid))
+        with span("engine.arrive", rid=rid,
+                  prompt_len=self.reqs[rid].prompt_len):
+            self._price_piggyback(now)
+            # engine-level controller observes arrivals unless the policy
+            # owns the same controller (DynamicTetrisPolicy observes via
+            # on_arrival)
+            if (self.controller is not None
+                    and getattr(self.policy, "controller", None)
+                    is not self.controller):
+                self.controller.observe(now)
+            skip = self._host_prefix_skip(rid)
+            if skip:
+                # host-cache-aware plan: only the uncached remainder is
+                # chunked; the cached prefix rides in as promoted pages
+                req = self.reqs[rid]
+                self.tracer.record(now, "arrive", rid=rid,
+                                   track=("request", rid), host_skip=skip)
+                self.policy.on_arrival(now)
+                shadow = Request(rid=rid, arrival=now,
+                                 prompt_len=req.prompt_len - skip,
+                                 output_len=req.output_len,
+                                 cached_tokens=skip)
+                alloc = self.policy.plan(shadow, self._pool_view(now), now)
+                if alloc is None:
+                    self.rejected.append(rid)
+                    self.tracer.record(now, "reject", rid=rid,
+                                       track=("request", rid))
+                    return
+                self._host_skip[rid] = skip
+                self._prefill[rid] = _PrefillState()
+                self._commit_plan(now, req, alloc)
                 return
-            self._host_skip[rid] = skip
-            self._prefill[rid] = _PrefillState()
-            self._commit_plan(now, req, alloc)
-            return
-        super()._on_arrive(now, rid)
-        if self.reqs[rid].chunk_plan is not None:
-            self._prefill[rid] = _PrefillState()
+            super()._on_arrive(now, rid)
+            if self.reqs[rid].chunk_plan is not None:
+                self._prefill[rid] = _PrefillState()
 
     def _positions(self, off: int, L: int) -> jax.Array:
         pos = jnp.arange(off, off + L, dtype=jnp.int32)
@@ -743,51 +742,61 @@ class ServingEngine(Simulator):
             # prefill pool: keep chunk order, try again shortly
             self._push(now + 0.05, "chunk_start", payload)
             return
-        skip = self._host_skip.pop(rid, None)
-        if skip and not self._promote_host_prefix(now, rid, skip, payload):
-            return
-        # prefill-direct-to-pages: grow this request's prefill-pool
-        # allocation to cover the chunk, run the chunk against the paged
-        # cross-chunk history, and scatter its KV into the pages — no
-        # dense per-request KV tree is ever built
-        self.pblocks.open(rid)
-        if not self.pblocks.extend(rid, st.off + L):
-            self._prefill_backpressure(now, rid, payload)
-            return
-        super()._on_chunk_start(now, payload)
-        toks = jnp.asarray(seq[None, st.off:st.off + L])
-        pos = self._positions(st.off, L)
-        alloc = self.pblocks.allocs[rid]
-        hist_bt = alloc[:self.pblocks.blocks_for(st.off)]
-        st.logits, new_caches, st.aux = prefill_chunk_paged(
-            self.params, self.cfg, self.ctx, toks, pos,
-            self.pkv.pools, hist_bt, st.off, st.aux)
-        with self.profiler.op("scatter_chunk"):
-            self.pkv.write_chunk(alloc, new_caches, pos,
-                                 active=self.pblocks.active_shards)
-        st.off += L
-        self.chunk_log.setdefault(rid, []).append({
-            "chunk": ci, "len": L, "sp": sp,
-            "sched_start": req.chunk_sched[ci][0],
-            "sched_end": req.chunk_sched[ci][1], "exec_start": now})
-        if self.controller is not None:
-            pool = self._pool_view(now)
-            self.controller.observe_queue(
-                now, sum(pool.values()) / max(len(pool), 1))
-            self._maybe_restripe(now)
-        self._run_piggyback(now, rid, ci)
-        if st.off >= len(seq):
-            self._preempt_flags.discard(rid)   # nothing left to preempt
-            prior = self._resume.pop(rid, None)
-            if prior is not None:
-                # recompute resume: greedy decoding is deterministic, so
-                # the re-prefill regenerates the same prefix — restore the
-                # already-emitted tokens rather than re-emitting them
-                self.outputs[rid] = prior
-            else:
-                self.outputs[rid] = [int(jnp.argmax(
-                    st.logits[0, 0, :self.cfg.vocab_size]))]
-            self._resume_seq.pop(rid, None)
+        # the history this chunk attends over: a planned host-prefix skip
+        # is promoted into pages before it runs
+        hist = self._host_skip.get(rid) or st.off
+        with span("engine.chunk", rid=rid, chunk=ci, len=L, hist=hist,
+                  sp=sp):
+            with span("engine.chunk.pages"):
+                skip = self._host_skip.pop(rid, None)
+                if skip and not self._promote_host_prefix(now, rid, skip,
+                                                          payload):
+                    return
+                # prefill-direct-to-pages: grow this request's prefill-pool
+                # allocation to cover the chunk, run the chunk against the
+                # paged cross-chunk history, and scatter its KV into the
+                # pages — no dense per-request KV tree is ever built
+                self.pblocks.open(rid)
+                if not self.pblocks.extend(rid, st.off + L):
+                    self._prefill_backpressure(now, rid, payload)
+                    return
+            super()._on_chunk_start(now, payload)
+            with span("engine.chunk.forward"):
+                toks = jnp.asarray(seq[None, st.off:st.off + L])
+                pos = self._positions(st.off, L)
+                alloc = self.pblocks.allocs[rid]
+                hist_bt = alloc[:self.pblocks.blocks_for(st.off)]
+                st.logits, new_caches, st.aux = prefill_chunk_paged(
+                    self.params, self.cfg, self.ctx, toks, pos,
+                    self.pkv.pools, hist_bt, st.off, st.aux)
+            with span("engine.chunk.scatter"):
+                self.pkv.write_chunk(alloc, new_caches, pos,
+                                     active=self.pblocks.active_shards)
+            st.off += L
+            self.chunk_log.setdefault(rid, []).append({
+                "chunk": ci, "len": L, "sp": sp,
+                "sched_start": req.chunk_sched[ci][0],
+                "sched_end": req.chunk_sched[ci][1], "exec_start": now})
+            if self.controller is not None:
+                pool = self._pool_view(now)
+                self.controller.observe_queue(
+                    now, sum(pool.values()) / max(len(pool), 1))
+                self._maybe_restripe(now)
+            self._run_piggyback(now, rid, ci)
+            if st.off >= len(seq):
+                self._preempt_flags.discard(rid)   # nothing left to preempt
+                prior = self._resume.pop(rid, None)
+                if prior is not None:
+                    # recompute resume: greedy decoding is deterministic,
+                    # so the re-prefill regenerates the same prefix —
+                    # restore the already-emitted tokens rather than
+                    # re-emitting them
+                    self.outputs[rid] = prior
+                else:
+                    with span("engine.chunk.first_token"):
+                        self.outputs[rid] = [int(jnp.argmax(
+                            st.logits[0, 0, :self.cfg.vocab_size]))]
+                self._resume_seq.pop(rid, None)
 
     def _prefill_backpressure(self, now: float, rid: int, payload) -> None:
         """Prefill page pool exhausted: apply backpressure, never crash.
@@ -948,11 +957,11 @@ class ServingEngine(Simulator):
                   or max(bm.kv_shards for bm, _ in self._pool_pairs()),
                   max(bm.kv_shards for bm, _ in self._pool_pairs()))
         migrated = 0
-        for bm, kv in self._pool_pairs():
-            pairs = bm.restripe(min(n, bm.kv_shards))
-            with self.profiler.op("restripe_all_to_all"):
+        with span("engine.restripe", n=n):
+            for bm, kv in self._pool_pairs():
+                pairs = bm.restripe(min(n, bm.kv_shards))
                 kv.restripe(pairs)
-            migrated += len(pairs)
+                migrated += len(pairs)
         self.ctx = self.ctx.with_(active_pool_shards=n)
         self.tracer.record(now, "restripe",
                            entry={"t": now, "n_old": old, "n_new": n,
@@ -1067,35 +1076,40 @@ class ServingEngine(Simulator):
             # plan is recomputed from scratch on the retry)
             self._push(now + 0.05, "transfer_done", rid)
             return
-        d.transfers.complete(rid)
-        st = self._prefill.pop(rid)
-        blocks = d.blocks.commit(rid, shared=shared)
-        # second-tier prefix cache: past the device-resident match (full
-        # blocks only — a shared partial tail ends the chain), continue
-        # the hash chain through demoted host pages and promote the hits
-        # back page-granularly instead of copying from the prefill pool
-        promo: List[int] = []
-        if (self.prefix_sharing and self.host_cache is not None
-                and len(shared) * d.block_size == shared_tok):
-            promo = self.host_cache.match_chain(
-                hashes[len(shared):], seq, len(shared), d.block_size)
-        # page-granular handoff: only the non-shared suffix pages move
-        # from the prefill pool; the shared prefix is served in place by
-        # the sibling's pages.  No dense per-request KV view exists.
-        if promo:
-            d.kv.copy_from(self.host, promo,
-                           blocks[len(shared):len(shared) + len(promo)])
-            d.transfers.note_swap("promote", TransferManager.swap_bytes(
-                len(promo), d.block_size, self.spec.kv_bytes_per_token))
-        skip = len(shared) + len(promo)
-        src = self.pblocks.allocs[rid]
-        d.kv.copy_from(self.pkv, src[skip:], blocks[skip:])
-        if self.prefix_sharing:
-            d.blocks.register_hashes(rid, hashes, tokens=seq)
-        d.insert(row, rid, st.aux, resident, self.outputs[rid][-1],
-                 blocks, shared_tok, seq)
-        d.meta[rid].hashes = list(hashes)     # chain seed for decode growth
-        self.pblocks.release(rid)
+        with span("engine.admit", rid=rid):
+            d.transfers.complete(rid)
+            st = self._prefill.pop(rid)
+            blocks = d.blocks.commit(rid, shared=shared)
+            # second-tier prefix cache: past the device-resident match
+            # (full blocks only — a shared partial tail ends the chain),
+            # continue the hash chain through demoted host pages and
+            # promote the hits back page-granularly instead of copying
+            # from the prefill pool
+            promo: List[int] = []
+            if (self.prefix_sharing and self.host_cache is not None
+                    and len(shared) * d.block_size == shared_tok):
+                promo = self.host_cache.match_chain(
+                    hashes[len(shared):], seq, len(shared), d.block_size)
+            # page-granular handoff: only the non-shared suffix pages
+            # move from the prefill pool; the shared prefix is served in
+            # place by the sibling's pages.  No dense per-request KV view
+            # exists.
+            if promo:
+                d.kv.copy_from(self.host, promo,
+                               blocks[len(shared):len(shared) + len(promo)])
+                d.transfers.note_swap(
+                    "promote", TransferManager.swap_bytes(
+                        len(promo), d.block_size,
+                        self.spec.kv_bytes_per_token))
+            skip = len(shared) + len(promo)
+            src = self.pblocks.allocs[rid]
+            d.kv.copy_from(self.pkv, src[skip:], blocks[skip:])
+            if self.prefix_sharing:
+                d.blocks.register_hashes(rid, hashes, tokens=seq)
+            d.insert(row, rid, st.aux, resident, self.outputs[rid][-1],
+                     blocks, shared_tok, seq)
+            d.meta[rid].hashes = list(hashes)   # chain seed for decode growth
+            self.pblocks.release(rid)
         self._stalled.discard(rid)            # back in a batch: stall over
         super()._on_transfer_done(now, rid)
         inst = self.decodes[req.decode_instance]
@@ -1282,15 +1296,16 @@ class ServingEngine(Simulator):
             self.host_cache.stats["rejected"] += len(fresh)
             return
         d = self.dstates[did]
-        pages = d.kv.read_blocks([b for b, _, _ in fresh])
-        self._demote_gathers += 1
-        stored = 0
-        for j, (b, h, tokens) in enumerate(fresh):
-            data = {layer: {part: arr[:, j:j + 1]
-                            for part, arr in parts.items()}
-                    for layer, parts in pages.items()}
-            if self.host_cache.put(h, tokens, data):
-                stored += 1
+        with span("engine.demote", blocks=len(fresh)):
+            pages = d.kv.read_blocks([b for b, _, _ in fresh])
+            self._demote_gathers += 1
+            stored = 0
+            for j, (b, h, tokens) in enumerate(fresh):
+                data = {layer: {part: arr[:, j:j + 1]
+                                for part, arr in parts.items()}
+                        for layer, parts in pages.items()}
+                if self.host_cache.put(h, tokens, data):
+                    stored += 1
         if stored:
             d.transfers.note_swap("demote", TransferManager.swap_bytes(
                 stored, d.block_size, self.spec.kv_bytes_per_token))
@@ -1778,76 +1793,84 @@ class ServingEngine(Simulator):
                 self.metrics.counter("ticks/deferred").inc()
                 self._push(bu, "decode_tick", did)
                 return
-        # every tick that passes while a recompute-preempted request is
-        # away (re-prefilling, in transfer, or waiting on a batch row) is
-        # a stalled token for that request — the drain-vs-restripe
-        # benchmark's cost metric
-        if self._stalled:
-            self.stall_ticks += len(self._stalled)
-            self.metrics.counter("restripe/stall_ticks").inc(
-                len(self._stalled))
-        self._grow_or_preempt(now, did)
-        # rows claimed by an in-flight swap-in have no meta yet: the KV is
-        # still crossing PCIe, so they sit this tick out
-        active = [r for r in d.slots if r is not None and r in d.meta]
-        if active:
-            if fused:
-                inst.piggyback_ticks += 1
-                inst.piggyback_tokens += len(active)
-            else:
-                inst.standalone_ticks += 1
-                inst.standalone_tokens += len(active)
-        if active:
-            B = d.max_batch
-            toks = np.zeros((B, 1), np.int32)
-            clen = np.zeros((B,), np.int32)
-            for r in active:
-                m = d.meta[r]
-                toks[m.row, 0] = m.last_token
-                clen[m.row] = m.cache_len
-            toks, clen = jnp.asarray(toks), jnp.asarray(clen)
-            pos = (jnp.broadcast_to(clen[None, :, None], (3, B, 1))
-                   if self.cfg.rope_type == "mrope" else clen[:, None])
-            bt = d.block_table(active)
-            caches = d.build_caches(active, bt)
-            with self.profiler.op("fused_tick" if fused
-                                  else "decode_tick"):
-                logits, _, new_caches = forward(
-                    self.params, self.cfg, self.ctx, toks, pos, "decode",
-                    caches=caches, cache_len=clen)
-                d.absorb(new_caches, active)
-            nxt = np.asarray(jnp.argmax(
-                logits[:, 0, :self.cfg.vocab_size], axis=-1))
-            for r in active:
-                m = d.meta[r]
-                m.tokens.append(m.last_token)   # its KV landed this tick
-                m.last_token = int(nxt[m.row])
-                m.cache_len += 1
-                self.outputs[r].append(int(nxt[m.row]))
-                if self.prefix_sharing and m.cache_len % d.block_size == 0:
-                    # a block filled *during decode*: extend the chained
-                    # hash by just this block and publish it, so
-                    # decode-grown prefixes are shareable by twin
-                    # admissions and demotable to the host tier
-                    bs = d.block_size
-                    prev = m.hashes[-1] if m.hashes else 0
-                    blk = m.tokens[len(m.hashes) * bs:m.cache_len]
-                    m.hashes.append(hash((prev,) + tuple(blk)))
-                    d.blocks.register_hashes(r, m.hashes, tokens=m.tokens)
-        # virtual-time bookkeeping + token accounting via the parent
-        inst = self.decodes[did]
-        finished_before = {r.rid for r in inst.batch
-                           if r.generated + 1 >= r.output_len}
-        super()._on_decode_tick(now, did)
-        for rid in finished_before:
-            meta = d.evict(rid)
-            if meta.shared_tokens:
-                inst.debit_shared(meta.shared_tokens)
-            self._decode_preempt_flags.discard(rid)
-        if (finished_before and self.fabric.cross_instance
-                and self.fabric.credit(did)):
-            # a finishing resident freed real blocks: give borrowed
-            # watermark headroom back to its donors
-            self.fabric.release_borrowed(
-                did, max(0, d.blocks.effective_free()
-                         - self._watermark_blocks(d)))
+        with span("engine.decode_tick", rows=len(d.meta), fused=fused):
+            with span("engine.decode_tick.grow"):
+                # every tick that passes while a recompute-preempted
+                # request is away (re-prefilling, in transfer, or waiting
+                # on a batch row) is a stalled token for that request —
+                # the drain-vs-restripe benchmark's cost metric
+                if self._stalled:
+                    self.stall_ticks += len(self._stalled)
+                    self.metrics.counter("restripe/stall_ticks").inc(
+                        len(self._stalled))
+                self._grow_or_preempt(now, did)
+            with span("engine.decode_tick.inputs"):
+                # rows claimed by an in-flight swap-in have no meta yet:
+                # the KV is still crossing PCIe, so they sit this tick out
+                active = [r for r in d.slots
+                          if r is not None and r in d.meta]
+                if active:
+                    if fused:
+                        inst.piggyback_ticks += 1
+                        inst.piggyback_tokens += len(active)
+                    else:
+                        inst.standalone_ticks += 1
+                        inst.standalone_tokens += len(active)
+                    B = d.max_batch
+                    toks = np.zeros((B, 1), np.int32)
+                    clen = np.zeros((B,), np.int32)
+                    for r in active:
+                        m = d.meta[r]
+                        toks[m.row, 0] = m.last_token
+                        clen[m.row] = m.cache_len
+                    toks, clen = jnp.asarray(toks), jnp.asarray(clen)
+                    pos = (jnp.broadcast_to(clen[None, :, None], (3, B, 1))
+                           if self.cfg.rope_type == "mrope"
+                           else clen[:, None])
+                    bt = d.block_table(active)
+                    caches = d.build_caches(active, bt)
+            if active:
+                with span("engine.decode_tick.forward"):
+                    logits, _, new_caches = forward(
+                        self.params, self.cfg, self.ctx, toks, pos,
+                        "decode", caches=caches, cache_len=clen)
+                    d.absorb(new_caches, active)
+                with span("engine.decode_tick.sync"):
+                    nxt = np.asarray(jnp.argmax(
+                        logits[:, 0, :self.cfg.vocab_size], axis=-1))
+            with span("engine.decode_tick.bookkeep"):
+                for r in active:
+                    m = d.meta[r]
+                    m.tokens.append(m.last_token)   # its KV landed
+                    m.last_token = int(nxt[m.row])
+                    m.cache_len += 1
+                    self.outputs[r].append(int(nxt[m.row]))
+                    if (self.prefix_sharing
+                            and m.cache_len % d.block_size == 0):
+                        # a block filled *during decode*: extend the
+                        # chained hash by just this block and publish it,
+                        # so decode-grown prefixes are shareable by twin
+                        # admissions and demotable to the host tier
+                        bs = d.block_size
+                        prev = m.hashes[-1] if m.hashes else 0
+                        blk = m.tokens[len(m.hashes) * bs:m.cache_len]
+                        m.hashes.append(hash((prev,) + tuple(blk)))
+                        d.blocks.register_hashes(r, m.hashes,
+                                                 tokens=m.tokens)
+                # virtual-time bookkeeping + token accounting via the
+                # parent
+                finished_before = {r.rid for r in inst.batch
+                                   if r.generated + 1 >= r.output_len}
+                super()._on_decode_tick(now, did)
+                for rid in finished_before:
+                    meta = d.evict(rid)
+                    if meta.shared_tokens:
+                        inst.debit_shared(meta.shared_tokens)
+                    self._decode_preempt_flags.discard(rid)
+                if (finished_before and self.fabric.cross_instance
+                        and self.fabric.credit(did)):
+                    # a finishing resident freed real blocks: give
+                    # borrowed watermark headroom back to its donors
+                    self.fabric.release_borrowed(
+                        did, max(0, d.blocks.effective_free()
+                                 - self._watermark_blocks(d)))

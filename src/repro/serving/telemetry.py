@@ -35,6 +35,13 @@ all report through:
   ``Tracer.tbt_causes`` tags every inter-token gap with its cause
   (standalone tick, fused window, swap, preempt, restripe, deferral).
 
+* **Wall-clock spans** — ``span`` opens a ``jax.profiler.TraceAnnotation``
+  around the host work of an engine handler (names in ``SPANS``).  The
+  spans live on the profiler's clock, the one the device's operations
+  are traced on, not on the Tracer's event clock: they record nothing in
+  ``Tracer.events`` or the registry, and outside a profiler session
+  they cost about a microsecond each.
+
 Exactness: all components except ``queue_wait`` are measured by walking
 the request's lifecycle events as a state machine over consecutive
 ``[last_event, this_event]`` intervals (clipped to the TTFT window — no
@@ -42,23 +49,25 @@ interval is ever double-counted).  ``queue_wait`` — definitionally the
 unattributed remainder — is then chosen so the left-to-right float sum
 in ``ATTRIBUTION_ORDER`` reproduces the observed TTFT bit-for-bit
 (``exact_remainder``: the naive remainder nudged by ULPs until the
-fixed-order sum is exact).  ``attribution_total`` is the canonical
-summation every consumer must use.
+fixed-order sum is exact).  The measured components are first snapped to
+the grain of the observed TTFT (its ULP), which keeps the target inside
+the domain where such a remainder exists.  ``attribution_total`` is the
+canonical summation every consumer must use.
 """
 
 from __future__ import annotations
 
 import json
 import math
-import time
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "ATTRIBUTION_ORDER", "Counter", "FABRIC_METRICS", "Gauge",
-    "Histogram", "MetricsRegistry", "OpProfiler", "TraceEvent", "Tracer",
-    "attribution_total", "build_trace_doc", "exact_remainder",
+    "Histogram", "MetricsRegistry", "SPANS", "TraceEvent", "Tracer",
+    "attribution_total", "build_trace_doc", "exact_remainder", "span",
 ]
 
 # Canonical metric names published by the cluster KV fabric
@@ -78,6 +87,35 @@ FABRIC_METRICS = (
     "fabric/interconnect_bytes",  # device-to-device bytes, all causes
     "fabric/leases_active",       # gauge: leases currently outstanding
 )
+
+# Canonical names of the engine's wall-clock spans (``span``), parents
+# before their children.  A child runs inside its parent on the handler's
+# thread; the five ``engine.decode_tick.*`` children cover a tick's work.
+SPANS = (
+    "engine.arrive",                # host-prefix peek + CDSP plan
+    "engine.chunk",                 # one prefill chunk
+    "engine.chunk.pages",           # prefill-pool pages, host promotion
+    "engine.chunk.forward",         # prefill_chunk_paged dispatch
+    "engine.chunk.scatter",         # the chunk's KV into its pages
+    "engine.chunk.first_token",     # last chunk: argmax to the host
+    "engine.admit",                 # prefill pool -> decode pool copy
+    "engine.decode_tick",           # one decode step of a batch
+    "engine.decode_tick.grow",      # page growth, CoW, preemption
+    "engine.decode_tick.inputs",    # tokens, lengths, block table, caches
+    "engine.decode_tick.forward",   # forward + absorb dispatch
+    "engine.decode_tick.sync",      # argmax to the host (waits on device)
+    "engine.decode_tick.bookkeep",  # tokens, hashes, accounting, evictions
+    "engine.restripe",              # the pools' all-to-all
+    "engine.demote",                # freed pages into the host prefix cache
+)
+
+
+def span(name: str, **args: Any) -> TraceAnnotation:
+    """A wall-clock span around host work: ``with span("engine.chunk",
+    rid=3, len=512): ...``.  ``name`` is one of ``SPANS``; ``args`` are
+    cheap scalars (ints, bools), stored as the event's stats only while a
+    profiler session records.  Nothing goes to a Tracer or a registry."""
+    return TraceAnnotation(name, **args)
 
 
 # ---------------------------------------------------------------- metrics
@@ -193,31 +231,6 @@ class MetricsRegistry:
         }
 
 
-class OpProfiler:
-    """Optional wall-clock hooks around jitted page ops.  Disabled it is
-    a no-op context manager; enabled it feeds ``op_wall_us/<name>``
-    histograms in the registry.  Timings are host wall clock around the
-    call — under jax async dispatch they bound enqueue+sync cost, not
-    pure device time (documented caveat, good enough for spotting a page
-    op that suddenly dominates)."""
-
-    def __init__(self, metrics: MetricsRegistry, enabled: bool = False):
-        self.metrics = metrics
-        self.enabled = enabled
-
-    @contextmanager
-    def op(self, name: str):
-        if not self.enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.metrics.hist(f"op_wall_us/{name}").observe(
-                (time.perf_counter() - t0) * 1e6)
-
-
 # ----------------------------------------------------------- attribution
 # Canonical summation order for TTFT attribution.  ``queue_wait`` is the
 # exact remainder and MUST come last; every consumer sums left-to-right
@@ -240,21 +253,30 @@ def exact_remainder(target: float, measured: Iterable[float]) -> float:
     """The value ``q`` such that summing ``[*measured, q]`` left-to-right
     in float arithmetic yields exactly ``target``.
 
+    Contract: such a ``q`` exists only where the fixed-order sum ``s`` of
+    ``measured`` can reach ``target``, and there it is found.  Outside
+    that domain this raises ``ValueError``.  Floats near ``q`` may be
+    spaced wider than the target's ULP (``s`` far above ``target``, e.g.
+    ``s = 1.0`` and ``target = 1e-300``), or ``target - s`` may fall
+    exactly halfway between two of them with a tie that rounds away from
+    ``target``.  ``s`` a multiple of ``math.ulp(target)`` and at most
+    ``2 * target`` is always inside: ``target - s`` is then a float.
+    ``Tracer.attribution`` snaps its components to that grain, so it
+    always sits inside.
+
     Starts from the naive remainder and walks it by ULPs toward the
-    correction (a short fixpoint: float addition is monotonic in each
-    argument, so the walk terminates in a few steps)."""
+    correction: float addition is monotonic in each argument, so a
+    reachable target is hit within a few steps."""
     s = 0.0
     for v in measured:
         s += v
     q = target - s
-    for _ in range(64):
+    for _ in range(4):
         got = s + q
         if got == target:
             return q
         q = math.nextafter(q, math.inf if got < target else -math.inf)
-    # pathological cancellation (never seen on event-clock floats): fall
-    # back to the naive remainder — callers detect via attribution_total
-    return target - s
+    raise ValueError(f"no float q makes {s!r} + q == {target!r}")
 
 
 # ---------------------------------------------------------------- tracer
@@ -419,9 +441,16 @@ class Tracer:
             # trailing interval: the request stayed in its final state
             # until the window closed (the remainder is queue_wait)
             accrue(state, last, win1)
-        measured = [comps[k] for k in ATTRIBUTION_ORDER
-                    if k != "queue_wait"]
-        comps["queue_wait"] = exact_remainder(win1 - win0, measured)
+        # snap the measured components to the TTFT's grain (its ULP, a
+        # power of two, so the snap is exact): their sum is then a
+        # multiple of the grain, inside ``exact_remainder``'s domain
+        ttft = win1 - win0
+        grain = math.ulp(ttft)
+        measured = []
+        for k in ATTRIBUTION_ORDER[:-1]:
+            comps[k] = round(comps[k] / grain) * grain
+            measured.append(comps[k])
+        comps["queue_wait"] = exact_remainder(ttft, measured)
         return comps
 
     # --------------------------------------------------- TBT attribution

@@ -7,11 +7,17 @@ machine over RANDOM synthetic preempt/swap/restripe lifecycles (the
 bit-equality and partition guarantees must hold for *any* event
 sequence, so random schedules are the honest test), and one real traced
 engine run under block pressure (swap preemptions + fused and deferred
-ticks) checks the recording sites end to end.
+ticks) checks the recording sites end to end.  The engine's wall-clock
+spans are checked in a profiler trace, and against a run without them.
+Only this module starts a profiler session.
 """
 
+import contextlib
+import glob
 import json
 import math
+import os
+from fractions import Fraction
 
 import jax
 import numpy as np
@@ -58,28 +64,47 @@ def test_registry_counters_gauges_hists():
     assert 1e-3 <= m.hist("h").percentile(50) <= 2e-3
 
 
+def _reachable(s: float, target: float) -> bool:
+    """Whether some float q makes ``s + q == target``, decided in exact
+    rationals: the q that work form an interval of floats around the
+    exact difference, so it holds iff one of the floats nearest that
+    difference works."""
+    q = float(Fraction(target) - Fraction(s))
+    return any(s + c == target for c in (
+        q, math.nextafter(q, -math.inf), math.nextafter(q, math.inf)))
+
+
 def test_exact_remainder_property():
+    """Bit-equal wherever the fixed-order sum can reach the target, a
+    ``ValueError`` everywhere else; a sum on the target's grain of at
+    most twice the target (what attribution hands in) always reaches."""
     @settings(max_examples=50, deadline=None)
     @given(st.lists(st.floats(min_value=0.0, max_value=10.0), min_size=0,
                     max_size=8),
            st.floats(min_value=0.0, max_value=100.0))
     def prop(measured, target):
-        q = exact_remainder(target, measured)
         s = 0.0
         for v in measured:
             s += v
-        assert s + q == target             # bit-equal by construction
+        if _reachable(s, target):
+            assert s + exact_remainder(target, measured) == target
+        else:
+            with pytest.raises(ValueError):
+                exact_remainder(target, measured)
+        if s > 2 * target:
+            return
+        grain = math.ulp(target)
+        snapped = [round(v / grain) * grain for v in measured]
+        s = 0.0
+        for v in snapped:
+            s += v
+        if s <= 2 * target:
+            assert s + exact_remainder(target, snapped) == target
     prop()
-
-
-def test_op_profiler_disabled_and_enabled():
-    m = MetricsRegistry()
-    with telemetry.OpProfiler(m, enabled=False).op("x"):
-        pass
-    assert "op_wall_us/x" not in m.hists
-    with telemetry.OpProfiler(m, enabled=True).op("x"):
-        pass
-    assert m.hist("op_wall_us/x").count == 1
+    # outside the domain: the floats near -1.0 are 2**-53 apart
+    with pytest.raises(ValueError):
+        exact_remainder(1e-300, [1.0])
+    assert exact_remainder(0.0, [1.0]) == -1.0
 
 
 # --------------------------------------------------------- tracer basics
@@ -257,13 +282,11 @@ class _TwoChunkPolicy(Policy):
         return Allocation([Chunk(L, (base,), 0.0, t)])
 
 
-@pytest.fixture(scope="module")
-def traced_pressure_run(reduced_params_cache):
+def _pressure_run(cfg, params):
     """One colocated piggyback run under block pressure with the swap
     preemption policy: exercises chunks, fused AND deferred ticks,
     swap-out/swap-in round trips, transfers and finishes."""
     from repro.serving.engine import ServingEngine
-    cfg, params = reduced_params_cache("yi-9b")
     spec = ClusterSpec(n_prefill=8, n_decode=1, sp_candidates=(1, 2, 4))
     eng = ServingEngine(cfg, params, spec, _TwoChunkPolicy(MODEL, spec),
                         max_batch=4, max_seq=64, block_size=16,
@@ -277,6 +300,11 @@ def traced_pressure_run(reduced_params_cache):
                    rng.integers(0, cfg.vocab_size, 60))
     out = eng.serve()
     return eng, out
+
+
+@pytest.fixture(scope="module")
+def traced_pressure_run(reduced_params_cache):
+    return _pressure_run(*reduced_params_cache("yi-9b"))
 
 
 def test_engine_run_attribution_bit_equal(traced_pressure_run):
@@ -481,3 +509,110 @@ def test_simulator_tracing_off_by_default():
             continue
         comps = sim2.tracer.attribution(r.rid, r.arrival, r.prefill_done)
         assert attribution_total(comps) == r.ttft
+
+
+# -------------------------------------------------- wall-clock spans
+def _host_spans(log_dir):
+    """``[(start_ns, end_ns, name, stats, thread)]`` of the engine's spans
+    in the profiler trace under ``log_dir``, in start order."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine."):
+                    out.append((e.start_ns, e.end_ns, e.name,
+                                dict(e.stats), (plane.name, line.name)))
+    return sorted(out, key=lambda x: (x[0], -x[1]))
+
+
+def test_engine_spans_in_a_profiler_trace(tmp_path, reduced_params_cache):
+    """Two requests of two chunks each, colocated so that the second
+    one's chunks run ticks of the first fused inside them, served inside
+    a profiler session: the handlers' spans are in the trace under their
+    canonical names, each tick's children nest inside it with exactly one
+    sync, a fused tick nests inside its chunk, the chunk spans carry
+    their request and length, and a finish's demotion to the host prefix
+    cache nests inside its tick's bookkeeping.  The tick after the last row finishes has
+    no rows, so it runs no step."""
+    from repro.serving.engine import ServingEngine
+    cfg, params = reduced_params_cache("yi-9b")
+    spec = ClusterSpec(n_prefill=8, n_decode=1, sp_candidates=(1, 2, 4))
+    eng = ServingEngine(cfg, params, spec, _TwoChunkPolicy(MODEL, spec),
+                        max_batch=4, max_seq=64, block_size=16,
+                        decode_hosts={0: tuple(range(8))},
+                        prefill_pool_blocks=64)
+    rng = np.random.default_rng(2)
+    for i, a in enumerate((0.0, 0.13)):
+        eng.submit(Request(rid=i, arrival=a, prompt_len=40, output_len=6),
+                   rng.integers(0, cfg.vocab_size, 40))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.serve()
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    names = {n for _, _, n, _, _ in spans}
+    assert names <= set(telemetry.SPANS), names - set(telemetry.SPANS)
+    assert {"engine.arrive", "engine.chunk", "engine.chunk.forward",
+            "engine.admit", "engine.decode_tick"} <= names
+    chunks = [x for x in spans if x[2] == "engine.chunk"]
+    assert sorted((x[3]["rid"], x[3]["len"]) for x in chunks) == sorted(
+        (rid, c["len"]) for rid, log in eng.chunk_log.items() for c in log)
+    assert len(chunks) == 4
+    ticks = [x for x in spans if x[2] == "engine.decode_tick"]
+    stepped = [x for x in ticks if x[3]["rows"] > 0]
+    ms = eng.mixed_stats
+    assert len(stepped) == ms["piggyback_ticks"] + ms["standalone_ticks"]
+    assert len(stepped) >= 3 and ms["piggyback_ticks"] > 0
+    kids = [x for x in spans if x[2].startswith("engine.decode_tick.")]
+    for a, b, name, _, thread in kids:
+        assert any(t[0] <= a and b <= t[1] and t[4] == thread
+                   for t in ticks), name
+    for a, b, _, stats, _ in ticks:
+        inside = [x[2] for x in kids if a <= x[0] and x[1] <= b]
+        parts = ("grow", "inputs", "forward", "sync", "bookkeep")
+        if stats["rows"] == 0:
+            parts = ("grow", "inputs", "bookkeep")
+        assert sorted(inside) == sorted(f"engine.decode_tick.{p}"
+                                        for p in parts), inside
+        in_chunk = any(c[0] <= a and b <= c[1] for c in chunks)
+        assert in_chunk == bool(stats["fused"])
+    assert sum(x[3]["fused"] for x in ticks) == ms["piggyback_ticks"]
+    # a finish demotes the request's published pages inside the tick
+    demotes = [x for x in spans if x[2] == "engine.demote"]
+    assert len(demotes) == eng._demote_gathers > 0
+    books = [x for x in kids if x[2] == "engine.decode_tick.bookkeep"]
+    for a, b, _, stats, _ in demotes:
+        assert stats["blocks"] > 0
+        assert any(k[0] <= a and b <= k[1] for k in books)
+
+
+def test_engine_spans_record_nothing_without_a_session(
+        monkeypatch, traced_pressure_run, reduced_params_cache):
+    """Outside a profiler session the spans leave the engine's own
+    records as they were: the same run with every span taken out gives
+    the same tokens, Tracer events, log views and registry."""
+    from repro.serving import engine as engine_mod
+    eng, out = traced_pressure_run
+    monkeypatch.setattr(engine_mod, "span",
+                        lambda name, **args: contextlib.nullcontext())
+    bare, bare_out = _pressure_run(*reduced_params_cache("yi-9b"))
+
+    def events(e):
+        return [(x.seq, x.t, x.kind, x.track, x.rid, x.dur,
+                 telemetry._jsonable(x.args)) for x in e.tracer.events]
+    assert out == bare_out
+    assert events(eng) == events(bare)
+    assert eng.preempt_log == bare.preempt_log and eng.preempt_log
+    assert eng.mixed_log == bare.mixed_log and eng.mixed_log
+    snap, bare_snap = eng.metrics.snapshot(), bare.metrics.snapshot()
+    assert snap["counters"] == bare_snap["counters"]
+    assert snap["gauges"] == bare_snap["gauges"]
+    assert set(snap["histograms"]) == set(bare_snap["histograms"])
+    assert not any(k.startswith("engine.") for part in snap.values()
+                   for k in part)
