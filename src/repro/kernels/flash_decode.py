@@ -17,8 +17,10 @@ Paged variants for the serving engine's block-table KV layout
 
 * ``paged_flash_decode`` — the same streaming kernel with the page table as
   a scalar-prefetch argument; the KV BlockSpec index map dereferences the
-  table so each grid step DMAs the right physical page (no materialised
-  dense copy).  This is the TPU execution path behind
+  table so each grid step DMAs the right physical page, in the pool's own
+  ``(page, KVH, D)`` layout (no materialised dense copy, no relayout of
+  the pool).  It also reads a layer-stacked pool ``(L, n_pages, page,
+  KVH, D)`` at a traced ``layer``.  This is the TPU execution path behind
   ``ops.paged_decode_attention``, which the model's decode attention uses
   natively (models/attention.py); on CPU the gather fallback in
   ``kernels/ref.paged_decode_attention_ref`` takes over.
@@ -35,10 +37,12 @@ Paged variants for the serving engine's block-table KV layout
   victim's pages off the device for a swap-out / demotion, scatter lands
   host pages back into the pool for a swap-in / prefix-cache promotion.
 * ``paged_append_attend`` — the fused decode tick: writes the new token's
-  K/V into its page AND attends in one donated jitted invocation (the
-  production path behind ``ops.paged_decode_attention(..., k_new, v_new)``
-  and the sharded decode island) — the pool is touched once per tick, not
-  scatter-then-gather.
+  K/V into its page AND attends in one jitted invocation (the production
+  path behind ``ops.paged_decode_attention(..., k_new, v_new)`` and the
+  sharded decode island) — the pool is touched once per tick, not
+  scatter-then-gather.  Its own donation holds only when it is called on
+  its own; inside the model's layer scan the decode step that donates the
+  pools is ``models/transformer._stack_forward``.
 * ``scatter_kv_token`` and ``gather_kv_pages`` are validation/debug
   helpers only; the production per-step append is the fused path above.
 
@@ -66,24 +70,21 @@ from repro.kernels.flash_attention import (NEG_INF, check_head_dim,
                                            init_scratch, pad_seq, seq_block)
 
 
-def _decode_step(q_ref, k_ref, v_ref, valid, acc_scr, m_scr, l_scr, *,
-                scale: float, group: int, D: int):
+def _decode_step(q_ref, k_heads, v_heads, valid, acc_scr, m_scr, l_scr, *,
+                scale: float, group: int):
     """One online-softmax step of single-token attention for all heads.
 
-    q_ref: (1, H, D); k_ref/v_ref: (1, bk, KVH * D) — one KV block with its
-    heads on the lane axis; valid: (1, bk).  Each KV head's scores are
-    computed for every query row and kept for the rows of its group, so
-    the body needs no sublane slicing whatever the group size; decode is
-    bandwidth bound, so the redundant MXU work is free.  Scores, softmax
-    weights and both matmuls are f32, as in kernels/ref.py."""
+    q_ref: (1, H, D); k_heads/v_heads: one KV block's (bk, D) slice per KV
+    head; valid: (1, bk).  Each KV head's scores are computed for every
+    query row and kept for the rows of its group, so the body needs no
+    sublane slicing whatever the group size; decode is bandwidth bound,
+    so the redundant MXU work is free.  Scores, softmax weights and both
+    matmuls are f32, as in kernels/ref.py."""
     q = q_ref[0].astype(jnp.float32) * scale                     # (H, D)
-    k = k_ref[0].astype(jnp.float32)                             # (bk, KVH*D)
-    v = v_ref[0].astype(jnp.float32)
-    KVH = k.shape[1] // D
     head_kv = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0) // group
     s = None
-    for h in range(KVH):
-        s_h = jax.lax.dot_general(q, k[:, h * D:(h + 1) * D],
+    for h, k_h in enumerate(k_heads):
+        s_h = jax.lax.dot_general(q, k_h.astype(jnp.float32),
                                   (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
         s = s_h if s is None else jnp.where(head_kv == h, s_h, s)
@@ -94,9 +95,9 @@ def _decode_step(q_ref, k_ref, v_ref, valid, acc_scr, m_scr, l_scr, *,
     alpha = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     pv = None
-    for h in range(KVH):
+    for h, v_h in enumerate(v_heads):
         p_h = jnp.where(head_kv == h, p, 0.0)
-        o_h = jax.lax.dot_general(p_h, v[:, h * D:(h + 1) * D],
+        o_h = jax.lax.dot_general(p_h, v_h.astype(jnp.float32),
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
         pv = o_h if pv is None else pv + o_h
@@ -141,8 +142,10 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(jnp.any(valid))
     def _compute():
-        _decode_step(q_ref, k_ref, v_ref, valid, acc_scr, m_scr, l_scr,
-                    scale=scale, group=group, D=D)
+        KVH = k_ref.shape[-1] // D
+        heads = lambda ref: [ref[0][:, h * D:(h + 1) * D] for h in range(KVH)]
+        _decode_step(q_ref, heads(k_ref), heads(v_ref), valid, acc_scr,
+                     m_scr, l_scr, scale=scale, group=group)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -513,10 +516,10 @@ def shard_copy_kv_block_within(pool, src_local, dst_local, *, mesh,
 POS_PAD = jnp.int32(2 ** 30)
 
 
-def _paged_decode_kernel(bt_ref, len_ref, pp_ref, q_ref, k_ref, v_ref,
-                         o_ref, lse_ref, acc_scr, m_scr, l_scr,
+def _paged_decode_kernel(layer_ref, bt_ref, len_ref, pp_ref, q_ref, k_ref,
+                         v_ref, o_ref, lse_ref, acc_scr, m_scr, l_scr,
                          *, scale: float, nk: int, bk: int, group: int,
-                         D: int, window: Optional[int]):
+                         window: Optional[int]):
     b = pl.program_id(0)
     ik = pl.program_id(1)
 
@@ -538,8 +541,11 @@ def _paged_decode_kernel(bt_ref, len_ref, pp_ref, q_ref, k_ref, v_ref,
 
     @pl.when(jnp.any(valid))
     def _compute():
-        _decode_step(q_ref, k_ref, v_ref, valid, acc_scr, m_scr, l_scr,
-                    scale=scale, group=group, D=D)
+        # k_ref/v_ref: one page as the pool stores it, (page, KVH, D)
+        KVH = k_ref.shape[1]
+        heads = lambda ref: [ref[:, h, :] for h in range(KVH)]
+        _decode_step(q_ref, heads(k_ref), heads(v_ref), valid, acc_scr,
+                     m_scr, l_scr, scale=scale, group=group)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -551,7 +557,7 @@ def _paged_decode_kernel(bt_ref, len_ref, pp_ref, q_ref, k_ref, v_ref,
     static_argnames=("window", "softmax_scale", "interpret", "with_lse"))
 def paged_flash_decode(
     q: jax.Array,                      # (B, H, D)
-    k_pool: jax.Array,                 # (n_pages, page, KVH, D)
+    k_pool: jax.Array,                 # ([L,] n_pages, page, KVH, D)
     v_pool: jax.Array,
     block_tables: jax.Array,           # (B, pages_per_seq) int32
     lengths: jax.Array,                # (B,) int32
@@ -561,11 +567,18 @@ def paged_flash_decode(
     interpret: bool = False,
     with_lse: bool = False,
     page_pos: Optional[jax.Array] = None,  # (B, pages_per_seq) int32
+    layer: Optional[jax.Array] = None,     # () int32, with an L axis
 ) -> jax.Array | Tuple[jax.Array, jax.Array]:
     """Flash decode straight off the paged pool: the block table is a
     scalar-prefetch argument and the KV BlockSpec index map dereferences it,
     so each (b, ik) grid step DMAs physical page ``block_tables[b, ik]``
-    (all KV heads of it, viewed as ``(page, KVH * D)``).
+    (all KV heads of it, in the pool's own ``(page, KVH, D)`` layout: no
+    relayout of the pool before the call).
+
+    The pools may carry a leading layer axis — the layer-stacked pools of
+    ``PagedKVCache`` — and ``layer`` (a traced scalar) then picks the
+    layer inside the index map, so a layer scan attends over its slice of
+    the stacked pool without slicing it out.
 
     ``page_pos[b, j]`` is the logical position of page j's first token
     (default: flat table order, ``j * page``).  A shard of a striped pool
@@ -574,8 +587,10 @@ def paged_flash_decode(
     positional gather slab, no contiguous-local-length requirement.
     Columns past the allocation should carry ``POS_PAD`` so they mask out.
     """
+    if k_pool.ndim == 4:               # one layer's pool: a unit layer axis
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     B, H, D = q.shape
-    n_pages, bk, KVH, _ = k_pool.shape
+    _, n_pages, bk, KVH, _ = k_pool.shape
     nk = block_tables.shape[1]
     check_head_dim(D, interpret)
     group = H // KVH
@@ -583,24 +598,24 @@ def paged_flash_decode(
     if page_pos is None:
         page_pos = jnp.broadcast_to(
             jnp.arange(nk, dtype=jnp.int32)[None] * bk, (B, nk))
-    kp = k_pool.reshape(n_pages, bk, KVH * D)
-    vp = v_pool.reshape(n_pages, bk, KVH * D)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale, nk=nk,
-                               bk=bk, group=group, D=D, window=window)
+                               bk=bk, group=group, window=window)
+    page_spec = pl.BlockSpec((None, None, bk, KVH, D),
+                             lambda b, ik, ly, bt, ln, pp:
+                             (ly[0], bt[b, ik], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,         # block_tables, lengths, page_pos
+        num_scalar_prefetch=4,         # layer, block_tables, lengths, page_pos
         grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, ik, bt, ln, pp: (b, 0, 0)),
-            pl.BlockSpec((1, bk, KVH * D),
-                         lambda b, ik, bt, ln, pp: (bt[b, ik], 0, 0)),
-            pl.BlockSpec((1, bk, KVH * D),
-                         lambda b, ik, bt, ln, pp: (bt[b, ik], 0, 0)),
+            pl.BlockSpec((1, H, D), lambda b, ik, ly, bt, ln, pp: (b, 0, 0)),
+            page_spec,
+            page_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, H, D), lambda b, ik, bt, ln, pp: (b, 0, 0)),
-            pl.BlockSpec((1, H, 1), lambda b, ik, bt, ln, pp: (b, 0, 0)),
+            pl.BlockSpec((1, H, D), lambda b, ik, ly, bt, ln, pp: (b, 0, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, ik, ly, bt, ln, pp: (b, 0, 0)),
         ],
         scratch_shapes=_decode_scratch(H, D),
     )
@@ -612,25 +627,26 @@ def paged_flash_decode(
             jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      page_pos.astype(jnp.int32), q, kp, vp)
+    )(layer, block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      page_pos.astype(jnp.int32), q, k_pool, v_pool)
     if with_lse:
         return out, lse[..., 0]
     return out
 
 
 def fused_append_attend(k_pool, v_pool, append_page, append_slot,
-                        k_new, v_new):
+                        k_new, v_new, layer=None):
     """The append half of the fused decode tick: write each sequence's new
-    token K/V into its page slot.  Rows routed to the scratch page (padded
-    batch rows; non-owning shards of a striped pool) write garbage that is
+    token K/V into its page slot (of layer ``layer`` of a layer-stacked
+    pool, when given).  Rows routed to the scratch page (padded batch
+    rows; non-owning shards of a striped pool) write garbage that is
     never read.  Shared by ``paged_append_attend`` and the sharded decode
     island — one invocation writes AND attends, so the pool is touched
     once per tick instead of scatter-then-gather."""
-    k_pool = k_pool.at[append_page, append_slot].set(
-        k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[append_page, append_slot].set(
-        v_new.astype(v_pool.dtype))
+    at = ((append_page, append_slot) if layer is None
+          else (layer, append_page, append_slot))
+    k_pool = k_pool.at[at].set(k_new.astype(k_pool.dtype))
+    v_pool = v_pool.at[at].set(v_new.astype(v_pool.dtype))
     return k_pool, v_pool
 
 
@@ -639,7 +655,7 @@ def fused_append_attend(k_pool, v_pool, append_page, append_slot,
     static_argnames=("window", "softmax_scale", "with_lse", "impl"))
 def paged_append_attend(
     q: jax.Array,                      # (B, H, D)
-    k_pool: jax.Array,                 # (n_pages, page, KVH, D) — donated
+    k_pool: jax.Array,                 # ([L,] n_pages, page, KVH, D) — donated
     v_pool: jax.Array,                 # donated
     block_tables: jax.Array,           # (B, pages_per_seq) int32
     lengths: jax.Array,                # (B,) int32, EXCLUDING the new token
@@ -648,6 +664,7 @@ def paged_append_attend(
     k_new: jax.Array,                  # (B, KVH, D)
     v_new: jax.Array,
     page_pos: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
     *,
     window: Optional[int] = None,
     softmax_scale: Optional[float] = None,
@@ -655,28 +672,30 @@ def paged_append_attend(
     impl: str = "pallas",
 ):
     """Fused append+attend decode tick: scatter the new token's K/V into
-    its page and attend over ``lengths + 1`` tokens in ONE donated jitted
-    invocation.  The pools are donated, so XLA performs the append as an
-    in-place dynamic-update on the live buffers and the attention reads
-    the updated pool directly — each tick stops paying a separate scatter
-    dispatch followed by a gather over the same page.
+    its page and attend over ``lengths + 1`` tokens in one jitted
+    invocation.  Called on its own, the pools are donated, so XLA performs
+    the append as an in-place dynamic-update on the live buffers.  Traced
+    inside a larger program (the model's layer scan) the donation here
+    means nothing: there the enclosing program owns the buffers, and the
+    decode tick's layer scan keeps the layer-stacked pools in its carry
+    and passes ``layer`` (models/transformer._stack_forward).
 
     Returns ``(o[, lse], k_pool, v_pool)``.
     """
     from repro.kernels import ref as _ref
     k_pool, v_pool = fused_append_attend(k_pool, v_pool, append_page,
-                                         append_slot, k_new, v_new)
+                                         append_slot, k_new, v_new, layer)
     att = lengths + 1
     if impl == "ref":
         o = _ref.paged_decode_attention_ref(
             q, k_pool, v_pool, block_tables, att, window=window,
             softmax_scale=softmax_scale, with_lse=with_lse,
-            page_pos=page_pos)
+            page_pos=page_pos, layer=layer)
     else:
         o = paged_flash_decode(
             q, k_pool, v_pool, block_tables, att, window=window,
             softmax_scale=softmax_scale, with_lse=with_lse,
-            interpret=(impl == "interpret"), page_pos=page_pos)
+            interpret=(impl == "interpret"), page_pos=page_pos, layer=layer)
     if with_lse:
         return o[0], o[1], k_pool, v_pool
     return o, k_pool, v_pool

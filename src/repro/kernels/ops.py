@@ -87,7 +87,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
                            window: Optional[int] = None, softmax_scale=None,
                            with_lse: bool = False, impl: Optional[str] = None,
                            page_pos=None, k_new=None, v_new=None,
-                           append_page=None, append_slot=None):
+                           append_page=None, append_slot=None, layer=None):
     """Block-table decode attention: one query token per sequence against a
     paged KV pool, no dense ``(batch, max_seq)`` cache anywhere.
 
@@ -106,6 +106,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     into its page inside the same (donated) invocation that attends —
     ``lengths`` then EXCLUDES the new token and the return value becomes
     ``(o[, lse], k_pool, v_pool)``; the pools are donated, so rebind them.
+
+    ``layer`` (2-dim tables): the pools are layer-stacked, ``(L, n_pages,
+    page, KVH, D)``, and the call appends to and attends over layer
+    ``layer`` of them without slicing it out — the decode layer scan's
+    in-place path (models/transformer._stack_forward).
 
     On TPU (``impl="pallas"``) this is ``paged_flash_decode`` — the block
     table rides in as a scalar-prefetch argument and the kernel DMAs pages
@@ -127,7 +132,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
         assert block_tables.ndim == 2, "fused append needs 2-dim tables"
         return _paged_append_attend(
             q, k_pool, v_pool, block_tables, lengths, append_page,
-            append_slot, k_new, v_new, page_pos, window=window,
+            append_slot, k_new, v_new, page_pos, layer, window=window,
             softmax_scale=softmax_scale, with_lse=with_lse,
             impl=("ref" if impl in ("ref", "ref_blocked") else impl))
     if block_tables.ndim == 3:
@@ -140,11 +145,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
         return _ref.paged_decode_attention_ref(
             q, k_pool, v_pool, block_tables, lengths, window=window,
             softmax_scale=softmax_scale, with_lse=with_lse,
-            page_pos=page_pos)
+            page_pos=page_pos, layer=layer)
     return _paged_flash_decode(q, k_pool, v_pool, block_tables, lengths,
                                window=window, softmax_scale=softmax_scale,
                                with_lse=with_lse, page_pos=page_pos,
-                               interpret=(impl == "interpret"))
+                               layer=layer, interpret=(impl == "interpret"))
 
 
 def paged_prefill_attention(q, k_new, v_new, q_pos, kv_pos_new,

@@ -186,7 +186,7 @@ def sharded_pool_view(pool: jax.Array, tables: jax.Array) -> jax.Array:
 
 def paged_decode_attention_ref(
     q: jax.Array,                      # (B, H, D)
-    k_pool: jax.Array,                 # (n_pages, page, KVH, D)
+    k_pool: jax.Array,                 # ([L,] n_pages, page, KVH, D)
     v_pool: jax.Array,
     block_tables: jax.Array,           # (B, pages_per_seq) int32 page ids
     lengths: jax.Array,                # (B,) int32 — valid cache length
@@ -195,6 +195,7 @@ def paged_decode_attention_ref(
     softmax_scale: Optional[float] = None,
     with_lse: bool = False,
     page_pos: Optional[jax.Array] = None,  # (B, pages_per_seq) int32
+    layer: Optional[jax.Array] = None,     # () int32, with an L axis
 ):
     """Single-token decode attention straight off a paged KV pool.
 
@@ -210,7 +211,9 @@ def paged_decode_attention_ref(
     logical position — a shard of a striped pool passes its pages' global
     stripe positions, so length AND window masks apply natively to the
     shard-local view (the per-shard paged decode path; matches the
-    kernel's scalar-prefetch argument of the same name).
+    kernel's scalar-prefetch argument of the same name).  ``layer`` picks
+    one layer of layer-stacked pools (2-dim tables only), gathered in the
+    same indexing as the pages, as the kernel's index map does.
 
     Also accepts the sequence-parallel sharded layout (3-dim
     ``block_tables`` (n_shards, B, npg_local) + 5-dim pools): the striped
@@ -224,9 +227,10 @@ def paged_decode_attention_ref(
         v = sharded_pool_view(v_pool, block_tables)
     else:
         B, npg = block_tables.shape
-        page = k_pool.shape[1]
-        k = k_pool[block_tables].reshape(B, npg * page, *k_pool.shape[2:])
-        v = v_pool[block_tables].reshape(B, npg * page, *v_pool.shape[2:])
+        page, kv_dims = k_pool.shape[-3], k_pool.shape[-2:]
+        at = block_tables if layer is None else (layer, block_tables)
+        k = k_pool[at].reshape(B, npg * page, *kv_dims)
+        v = v_pool[at].reshape(B, npg * page, *kv_dims)
         if page_pos is not None:
             kv_pos = (page_pos[:, :, None] +
                       jnp.arange(page, dtype=jnp.int32)[None, None]

@@ -87,11 +87,15 @@ def attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
       * paged — {"k","v","block_table"} where k/v are physical pools
         (n_pages, page, KVH, D) and block_table is (B, pages_per_seq)
         int32 page ids (the serving engine's BlockManager layout).  The
-        decode tick is FUSED: one donated ``ops.paged_decode_attention``
-        invocation writes the new token's K/V into its page slot AND
-        attends off the pool (Pallas scalar-prefetch kernel on TPU,
-        gather fallback elsewhere) — no dense (B, max_seq) view, no
-        scatter-then-gather over the same page.
+        decode tick is FUSED: one ``ops.paged_decode_attention`` call
+        writes the new token's K/V into its page slot AND attends off the
+        pool (Pallas scalar-prefetch kernel on TPU, gather fallback
+        elsewhere) — no dense (B, max_seq) view, no scatter-then-gather
+        over the same page.  With a ``"layer"`` entry the k/v are the
+        layer-stacked pools (n_blocks, n_pages, page, KVH, D) that the
+        decode layer scan carries, and the call writes and reads layer
+        ``layer`` of them in place (models/transformer._stack_forward,
+        which donates them); the updated stacked pools come back.
         A *sharded* paged cache — pools (n_shards, blocks_per_shard + 1,
         page, KVH, D) split over ctx.kv_split_axis, block_table
         (n_shards, B, npg_local) per-shard local ids — runs as a split-KV
@@ -166,14 +170,14 @@ def attention_block(x: jax.Array, p: dict, cfg: ModelConfig,
                 "sharded layout or run with ctx.with_(kv_split_axis"
                 "=None).")
         bt = cache["block_table"]                            # (B, npg) int32
-        page = cache["k"].shape[1]
+        page = cache["k"].shape[-3]
         bidx = jnp.arange(B)
-        # fused append+attend: the pools are donated — rebind them
+        # fused append+attend: rebind the pools that come back
         o, k_pool, v_pool = ops.paged_decode_attention(
             qd, cache["k"], cache["v"], bt, cache_len, window=window,
             impl=ctx.impl, k_new=k[:, 0], v_new=v[:, 0],
             append_page=bt[bidx, cache_len // page],
-            append_slot=cache_len % page)
+            append_slot=cache_len % page, layer=cache.get("layer"))
         out = out_proj(o[:, None], p, prefix)
         return out, {"k": k_pool, "v": v_pool, "block_table": bt}
 

@@ -13,9 +13,14 @@ structure: dict keyed by pattern position, leaves stacked over n_blocks.
 Decode attention caches come in two layouts (see models/attention.py):
 dense (B, S_max, KVH, D) buffers, or the serving engine's paged form —
 per-layer physical pools (n_blocks, n_pages, page, KVH, D) plus a shared
-``block_table`` leaf broadcast over n_blocks — which the scan threads
-through unchanged; the per-layer slice drops the n_blocks axis and the
-attention block consumes the table natively.
+``block_table`` leaf broadcast over n_blocks.  Dense buffers, recurrent
+and cross state, and the sharded paged layout (a 3-dim table per layer)
+ride the scan's ``xs``/``ys``: each block's slice goes in and a new
+stacked tree comes out.  The one-device paged layout (a 2-dim table per
+layer) does not: ``forward`` hands its pools to ``_stack_forward``
+separately, donated, the scan carries the stacked pools, and each layer
+appends to and attends over its own slice in place — no layer's pool is
+sliced out, relaid out or stacked back (``in_place_pools``).
 """
 
 from __future__ import annotations
@@ -98,57 +103,100 @@ def _residual_spec(ctx: ExecContext, mode: str):
     return (ctx.batch_axes, None, None)       # decode
 
 
+def in_place_pools(caches: dict) -> Tuple[dict, dict]:
+    """Split a decode cache tree into the paged pools that the layer scan
+    updates in place and the rest, which rides its ``xs``/``ys``.
+
+    A pool goes in place when its entry is paged with a 2-dim block table
+    per layer — the stacked leaf is (n_blocks, B, pages_per_seq) — which
+    is the one-device layout: the serving engine builds it whenever its
+    pool has a single shard.  Returns ``(pools, rest)``: ``pools`` maps
+    pattern position -> {"k","v"} stacked pools, and ``rest`` is the tree
+    with those entries' ``self`` cut to their block table.  Dense
+    buffers, the ring-buffer and windowed caches, recurrent and cross
+    state and the sharded layout (a 3-dim table per layer) stay in
+    ``rest`` whole."""
+    pools, rest = {}, {}
+    for key, ent in caches.items():
+        c = ent.get("self")
+        if c is not None and "block_table" in c and c["block_table"].ndim == 3:
+            pools[key] = {"k": c["k"], "v": c["v"]}
+            ent = dict(ent, self={"block_table": c["block_table"]})
+        rest[key] = ent
+    return pools, rest
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("cfg", "ctx", "mode", "causal", "pattern"))
+                   static_argnames=("cfg", "ctx", "mode", "causal", "pattern"),
+                   donate_argnames=("pools",))
 def _stack_forward(x, blocks_p, cfg: ModelConfig, ctx: ExecContext, positions,
                    mode: str, caches, cache_len, encoder_out,
-                   causal: bool, pattern, history=None):
+                   causal: bool, pattern, history=None, pools=None):
     """Scan over the stacked pattern blocks.
 
     Jitted so that an eagerly called forward (the serving engine's chunks
     and ticks) reuses the compiled layer stack for every call of the same
     shapes: an eager ``lax.scan`` traces a fresh body closure per call and
-    so compiled the whole stack again on every decode tick."""
+    so compiled the whole stack again on every decode tick.
+
+    ``pools`` (decode; see ``in_place_pools``): pattern position ->
+    {"k","v"} layer-stacked paged pools whose ``caches`` entry holds only
+    the block table.  They ride the scan's carry, and block ``l``'s layer
+    appends to and attends over slice ``l`` of them in place.  They are
+    donated, so the pools that come out (in the returned caches, beside
+    the block table, as the ``xs``/``ys`` path would return them) are the
+    buffers that went in."""
     res_spec = _residual_spec(ctx, mode)
     # constrained inside the jit: a sequence that does not divide the SP
     # axis shards unevenly here, where an eager constraint would refuse it
     x = ctx.constrain(x, *res_spec)
+    pools = pools or {}
 
     def body(carry, xs):
-        x, aux_tot = carry
-        block_p, block_cache, block_hist = xs
-        new_caches = {}
+        x, aux_tot, pools = carry
+        block_p, block_cache, block_hist, layer = xs
+        new_caches, pools = {}, dict(pools)
         for i, spec in enumerate(pattern):
-            c_i = None if block_cache is None else block_cache.get(str(i))
-            h_i = None if block_hist is None else block_hist.get(str(i))
-            x, nc, aux = _layer(x, spec, block_p[str(i)], cfg, ctx, positions,
+            key = str(i)
+            c_i = None if block_cache is None else block_cache.get(key)
+            h_i = None if block_hist is None else block_hist.get(key)
+            if key in pools:
+                c_i = dict(c_i, self=dict(c_i["self"], **pools[key],
+                                          layer=layer))
+            x, nc, aux = _layer(x, spec, block_p[key], cfg, ctx, positions,
                                 mode, c_i, cache_len, encoder_out, causal,
                                 history=h_i)
             x = ctx.constrain(x, *res_spec)
-            new_caches[str(i)] = nc
+            if key in pools:
+                c = nc.pop("self")
+                pools[key] = {"k": c["k"], "v": c["v"]}
+            new_caches[key] = nc
             aux_tot = aux_tot + aux
-        return (x, aux_tot), new_caches
+        return (x, aux_tot, pools), new_caches
 
     if ctx.remat and mode == "train":
         body = jax.checkpoint(body)
 
     aux0 = jnp.zeros((), jnp.float32)
+    nb = jax.tree.leaves(blocks_p)[0].shape[0]
     if ctx.unroll_scan:
-        nb = jax.tree.leaves(blocks_p)[0].shape[0]
-        carry = (x, aux0)
+        carry = (x, aux0, pools)
         ys = []
         for b in range(nb):
             xs_b = jax.tree.map(lambda a: a[b], (blocks_p, caches, history))
-            carry, y = body(carry, xs_b)
+            carry, y = body(carry, xs_b + (b,))
             ys.append(y)
-        (x, aux) = carry
+        (x, aux, pools) = carry
         if ys and jax.tree.leaves(ys[0]):
             new_caches = jax.tree.map(lambda *ls: jnp.stack(ls), *ys)
         else:
             new_caches = ys[0] if ys else {}
-        return x, aux, new_caches
-    (x, aux), new_caches = jax.lax.scan(body, (x, aux0),
-                                        (blocks_p, caches, history))
+    else:
+        (x, aux, pools), new_caches = jax.lax.scan(
+            body, (x, aux0, pools),
+            (blocks_p, caches, history, jnp.arange(nb, dtype=jnp.int32)))
+    for key, p in pools.items():
+        new_caches[key]["self"] = dict(p, **caches[key]["self"])
     return x, aux, new_caches
 
 
@@ -167,7 +215,9 @@ def forward(params: dict, cfg: ModelConfig, ctx: ExecContext,
     decode: tokens (B, 1); positions (B, 1) = cache_len; caches required —
     attention entries either dense per-sequence buffers or paged
     {"k","v","block_table"} pools (see models/attention.py); the updated
-    caches come back in the same layout.
+    caches come back in the same layout.  Pools of the one-device paged
+    layout are DONATED (``in_place_pools``): the ones passed in are
+    deleted, and the caller rebinds the pools that come back.
     """
     dtype = jnp.dtype(cfg.dtype)
     x = embed(tokens, params["embed"], dtype)
@@ -192,9 +242,13 @@ def forward(params: dict, cfg: ModelConfig, ctx: ExecContext,
             encoder_out = rms_norm(e, params["encoder"]["final_norm"],
                                    cfg.norm_eps)
 
+    pools = None
+    if mode == "decode":
+        pools, caches = in_place_pools(caches)
     x, aux, new_caches = _stack_forward(
         x, params["blocks"], cfg, ctx, positions, mode, caches, cache_len,
-        encoder_out, causal=True, pattern=cfg.pattern, history=history)
+        encoder_out, causal=True, pattern=cfg.pattern, history=history,
+        pools=pools or None)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if mode == "prefill":

@@ -670,13 +670,18 @@ class PagedKVCache:
     (n_blocks, total_blocks + 1, block_size, KVH, D): the leading n_blocks
     axis matches the transformer's layer scan, so the engine hands the
     pools straight into ``forward`` as the cache tree (decode) or the
-    paged history view (prefill, core/cdsp.pages_history_view) and the
-    scan slices one pool page-set per block.
+    paged history view (prefill, core/cdsp.pages_history_view).  A decode
+    tick donates the unsharded pools to the layer scan, which carries
+    them whole and appends to and attends over each layer's slice in
+    place (models/transformer.in_place_pools); ``adopt`` rebinds the
+    pools that come back.  A prefill chunk's scan still slices the
+    history pools one layer at a time as its ``xs``.
 
-    All writes rebind the pool arrays through donated jitted helpers, so
-    XLA aliases the buffers in place instead of functionally rebuilding
-    them — never keep an external reference to a pool array across a
-    write (see kernels/flash_decode.py).
+    All writes rebind the pool arrays through donated jitted helpers (and
+    the decode step), so XLA aliases the buffers in place instead of
+    functionally rebuilding them — never keep an external reference to a
+    pool array across a write or a decode tick (see
+    kernels/flash_decode.py).
 
     With ``kv_shards > 1`` the pools carry a device axis — per layer
     ``(n_blocks, kv_shards, blocks_per_shard + 1, block_size, KVH, D)``
@@ -1053,12 +1058,14 @@ class PagedKVCache:
 
     # -------------------------------------------------------------- decode
     def adopt(self, new_caches: dict) -> None:
-        """Fold one decode step's functionally-updated pools back in.
+        """Fold one decode step's updated pools back in.
 
-        The model's paged decode branch scattered each live row's new K/V
+        The model's paged decode branch wrote each live row's new K/V
         token into its page and returned the updated pools in the cache
-        tree; the pool arrays here are simply replaced (no copy — JAX
-        donated/updated buffers)."""
+        tree; the pool arrays here are simply replaced.  The unsharded
+        pools were donated to the step, so the arrays this held are
+        deleted and the new ones are the same buffers; the sharded
+        layout's are new buffers."""
         for i in self.attn_layers:
             ent = new_caches[str(i)]["self"]
             self.pools[str(i)] = {"k": ent["k"], "v": ent["v"]}

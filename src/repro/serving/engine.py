@@ -85,7 +85,7 @@ from repro.core.latency_model import (DecodeLatencyModel, HostOffloadModel,
                                       InterconnectModel)
 from repro.models.config import ModelConfig
 from repro.models.sharding import CPU_CTX, ExecContext
-from repro.models.transformer import forward
+from repro.models.transformer import forward, in_place_pools
 from repro.serving.cache_manager import (BlockManager, PagedKVCache,
                                          block_hashes)
 from repro.serving.kv_fabric import KVFabric
@@ -1829,6 +1829,13 @@ class ServingEngine(Simulator):
                            else clen[:, None])
                     bt = d.block_table(active)
                     caches = d.build_caches(active, bt)
+                    if d.kv.attn_layers:
+                        # forward donates the pools of the one-device
+                        # layout and the layer scan updates them in place;
+                        # the sharded layout's go through as xs/ys copies
+                        self.metrics.counter(
+                            "ticks/pool_in_place" if in_place_pools(caches)[0]
+                            else "ticks/pool_copied").inc()
             if active:
                 with span("engine.decode_tick.forward"):
                     logits, _, new_caches = forward(

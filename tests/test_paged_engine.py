@@ -2,6 +2,7 @@
 preemption/requeue (mid-prefill and decode-side), grow-on-demand block
 allocation under pool pressure, and the paged kernel primitives."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -65,6 +66,110 @@ def test_multichunk_paged_equivalence(arch, reduced_params_cache):
         want = _generate(params, cfg, prompt, len(outs[req.rid]))
         assert outs[req.rid] == want, f"rid {req.rid} diverged"
         assert req.done is not None
+    # every tick with rows took the in-place pool path; a model with no
+    # attention layer has no pools and counts neither
+    ticks = (eng.mixed_stats["standalone_ticks"]
+             + eng.mixed_stats["piggyback_ticks"])
+    counters = eng.metrics.snapshot()["counters"]
+    has_pools = any(sp.mixer == "attn" for sp in cfg.pattern)
+    assert counters.get("ticks/pool_in_place", 0) == (ticks if has_pools
+                                                      else 0)
+    assert "ticks/pool_copied" not in counters
+
+
+# ------------------------------------------- decode tick: pools in place
+def _paged_decode_state(cfg, params, lens=(9, 17, 12), page=8, npg=4):
+    """Prefill each row on its own and land its attention KV in a shared
+    page pool at permuted pages (the scratch page last), as the engine's
+    decode instance holds it.  Returns (caches, first tokens, lengths):
+    the decode cache tree ``DecodeInstance.build_caches`` would build."""
+    from repro.kernels.flash_decode import scatter_kv_prefill
+    from repro.models.sharding import CPU_CTX
+    from repro.models.transformer import forward
+    rng = np.random.default_rng(3)
+    B, nb = len(lens), cfg.n_blocks
+    n_pages = B * npg
+    pages = rng.permutation(n_pages).reshape(B, npg).astype(np.int32)
+    bt = jnp.broadcast_to(jnp.asarray(pages)[None], (nb, B, npg))
+    pool_shape = (nb, n_pages + 1, page, cfg.n_kv_heads, cfg.head_dim_)
+    caches, rows, first = {}, [], []
+    for i, spec in enumerate(cfg.pattern):
+        if spec.mixer == "attn":
+            caches[str(i)] = {"self": {
+                "k": jnp.zeros(pool_shape, jnp.dtype(cfg.dtype)),
+                "v": jnp.zeros(pool_shape, jnp.dtype(cfg.dtype)),
+                "block_table": bt}}
+    for b, L in enumerate(lens):
+        toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, L)),
+                           jnp.int32)
+        pos = jnp.arange(L, dtype=jnp.int32)[None]
+        logits, _, c = forward(params, cfg, CPU_CTX, toks, pos, "prefill")
+        first.append(int(jnp.argmax(logits[0, -1, :cfg.vocab_size])))
+        rows.append(c)
+        for key, ent in caches.items():
+            for part in ("k", "v"):
+                ent["self"][part] = scatter_kv_prefill(
+                    ent["self"][part], jnp.asarray(pages[b]),
+                    c[key]["self"][part][:, 0])
+    for i, spec in enumerate(cfg.pattern):
+        if spec.mixer != "attn":
+            caches[str(i)] = {"self": jax.tree.map(
+                lambda *xs: jnp.concatenate(xs, axis=1),
+                *[c[str(i)]["self"] for c in rows])}
+    return (caches, jnp.asarray(first, jnp.int32)[:, None],
+            jnp.asarray(lens, jnp.int32))
+
+
+@pytest.mark.parametrize("unroll", [False, True], ids=["scan", "unrolled"])
+@pytest.mark.parametrize("arch", ["yi-9b", "chatglm3-6b",
+                                  "jamba-1.5-large-398b"])
+def test_in_place_decode_matches_scan_path(reduced_params_cache, monkeypatch,
+                                           arch, unroll):
+    """The decode step that carries the paged pools through the layer
+    scan and updates them in place gives the same logits and the same
+    pools, tick after tick, as the path that slices each layer's pool out
+    as the scan's ``xs`` and stacks it back as its ``ys``; and it takes
+    the pools it is given (donated: deleted after the call), where the
+    ``xs``/``ys`` path leaves them alone.  Dense GQA (yi-9b), partial
+    rotary with a 16:1 group (chatglm3-6b), and an attention layer among
+    mamba and MoE layers (jamba), with the layer scan and unrolled."""
+    from repro.models import transformer
+    from repro.models.sharding import CPU_CTX
+    cfg, params = reduced_params_cache(arch)
+    ctx = CPU_CTX.with_(impl="ref", unroll_scan=unroll)
+    caches, toks, clen = _paged_decode_state(cfg, params)
+    attn = [k for k, e in caches.items() if "block_table" in e["self"]]
+    assert attn, "the architecture must have an attention layer"
+    copied = jax.tree.map(jnp.copy, caches)
+
+    def tick(caches, toks, clen):
+        logits, _, new = transformer.forward(
+            params, cfg, ctx, toks, clen[:, None], "decode", caches=caches,
+            cache_len=clen)
+        return logits, new
+
+    for _ in range(3):
+        given = [caches[k]["self"][p] for k in attn for p in ("k", "v")]
+        logits, caches = tick(caches, toks, clen)
+        assert all(a.is_deleted() for a in given), "pools not donated"
+        assert transformer.in_place_pools(copied)[0]
+        with monkeypatch.context() as m:
+            m.setattr(transformer, "in_place_pools", lambda c: ({}, c))
+            kept = [copied[k]["self"][p] for k in attn for p in ("k", "v")]
+            want, copied = tick(copied, toks, clen)
+            assert not any(a.is_deleted() for a in kept)
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        for k in attn:
+            for p in ("k", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(caches[k]["self"][p]),
+                    np.asarray(copied[k]["self"][p]))
+            assert (caches[k]["self"]["block_table"].shape
+                    == copied[k]["self"]["block_table"].shape)
+        toks = jnp.argmax(logits[:, :, :cfg.vocab_size], axis=-1).astype(
+            jnp.int32)
+        clen = clen + 1
 
 
 # ------------------------------------------------------------ event ordering
@@ -316,6 +421,24 @@ def test_engine_rejects_impossible_requests(reduced_params_cache):
                       rate_controller=DynamicRateController({1.0: 0.3}))
 
 
+def test_sharded_pool_keeps_the_scan_path():
+    """A decode pool sharded over a 2-device mesh (3-dim tables) keeps the
+    ``xs``/``ys`` path and ``ticks/pool_copied`` counts every tick; the
+    one-device engine updates in place and serves the same tokens.  Runs
+    in a subprocess with two host devices, which a process that has
+    initialised a one-device JAX cannot make."""
+    import os
+    import subprocess
+    import sys
+    prog = os.path.join(os.path.dirname(__file__), "dist_progs",
+                        "pool_path_prog.py")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, prog], capture_output=True,
+                         text=True, timeout=600, env=env)
+    assert out.returncode == 0, f"{out.stdout}\n{out.stderr}"
+    assert "DIST_OK" in out.stdout
+
+
 # ------------------------------------------------------- paged primitives
 def test_paged_gather_scatter_roundtrip():
     from repro.kernels.flash_decode import (gather_kv_pages,
@@ -378,7 +501,11 @@ def test_paged_decode_attention_op_matches_dense():
                                    atol=1e-5, rtol=1e-5)
 
 
-def test_paged_flash_decode_matches_ref():
+@pytest.mark.parametrize("layer", [None, 1], ids=["one_layer", "stacked"])
+def test_paged_flash_decode_matches_ref(layer):
+    """The interpret-mode kernel over one layer's pool, and over layer 1
+    of a three-layer stack (the others filled with noise), read through
+    the traced layer index as the decode layer scan passes it."""
     from repro.kernels.flash_decode import (paged_flash_decode,
                                             scatter_kv_prefill)
     from repro.kernels.ref import decode_attention_ref
@@ -389,17 +516,25 @@ def test_paged_flash_decode_matches_ref():
     k = jnp.asarray(rng.standard_normal((B, S, KVH, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, S, KVH, D)), jnp.float32)
     lengths = jnp.asarray([7, 20], jnp.int32)
-    pool_shape = (1, B * npg + 1, page, KVH, D)
-    kp, vp = jnp.zeros(pool_shape, jnp.float32), jnp.zeros(pool_shape,
-                                                           jnp.float32)
+    nl = 1 if layer is None else 3
+    pool_shape = (nl, B * npg + 1, page, KVH, D)
+    kp = jnp.asarray(rng.standard_normal(pool_shape), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(pool_shape), jnp.float32)
+    at = 0 if layer is None else layer
     perm = rng.permutation(B * npg)
     bt = np.zeros((B, npg), np.int32)
     for b in range(B):
         bt[b] = perm[b * npg:(b + 1) * npg]
-        kp = scatter_kv_prefill(kp, jnp.asarray(bt[b]), k[None, b])
-        vp = scatter_kv_prefill(vp, jnp.asarray(bt[b]), v[None, b])
-    got = paged_flash_decode(q, kp[0], vp[0], jnp.asarray(bt), lengths,
-                             interpret=True)
+        kp = kp.at[at].set(scatter_kv_prefill(kp[at][None], jnp.asarray(
+            bt[b]), k[None, b])[0])
+        vp = vp.at[at].set(scatter_kv_prefill(vp[at][None], jnp.asarray(
+            bt[b]), v[None, b])[0])
+    if layer is None:
+        kp, vp = kp[0], vp[0]
+    else:
+        layer = jnp.int32(layer)
+    got = paged_flash_decode(q, kp, vp, jnp.asarray(bt), lengths,
+                             interpret=True, layer=layer)
     want = decode_attention_ref(q, k, v, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
